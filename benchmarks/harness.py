@@ -37,6 +37,7 @@ if __package__ in (None, ""):  # direct script run: python benchmarks/<mod>.py
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks import registry
+from repro.runtime.compile_cache import enable_compile_cache
 
 __all__ = [
     "SCHEMA_VERSION",
@@ -284,6 +285,7 @@ def main(argv=None) -> int:
     ap.add_argument("--threshold", type=float, default=DEFAULT_THRESHOLD,
                     help=f"relative regression tolerance (default {DEFAULT_THRESHOLD})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     suites = registry.discover()
     if args.list or args.suite is None:

@@ -1,6 +1,6 @@
 """Interval abstract interpretation of jaxprs (the overflow/gather passes).
 
-Walks a traced :class:`jax.core.ClosedJaxpr` with every value summarized
+Walks a traced :class:`jax.extend.core.ClosedJaxpr` with every value summarized
 by a :class:`repro.analysis.domain.Interval` — O(1) work per equation
 regardless of tensor shape, so auditing realistic kernel envelopes is
 cheap.  Three families of checks fire as equations are interpreted:
@@ -41,6 +41,7 @@ import math
 from typing import Any, Callable
 
 import jax
+from jax.extend import core as jex_core
 import numpy as np
 
 from repro.analysis import domain
@@ -200,7 +201,7 @@ class Interpreter:
 
     # -- environment -------------------------------------------------
     def _read(self, env: dict, atom: Any) -> Any:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jex_core.Literal):
             return _const_interval(atom.val)
         return env[atom]
 
@@ -249,7 +250,7 @@ class Interpreter:
         env[outvar] = iv
 
     # -- jaxpr walk --------------------------------------------------
-    def run_closed(self, closed: jax.core.ClosedJaxpr, args: list[Any]) -> list[Any]:
+    def run_closed(self, closed: jex_core.ClosedJaxpr, args: list[Any]) -> list[Any]:
         consts = [_const_interval(c) for c in closed.consts]
         return self.run(closed.jaxpr, consts, args)
 
@@ -336,7 +337,7 @@ def interpret(spec: TraceSpec, policy: AuditPolicy | None = None) -> InterpRepor
 
 
 def interpret_closed(
-    closed: jax.core.ClosedJaxpr,
+    closed: jex_core.ClosedJaxpr,
     args: list[Interval],
     policy: AuditPolicy | None = None,
 ) -> InterpReport:
@@ -424,7 +425,7 @@ def _sub(self, env, eqn):
     out = domain.sub(a, b)
     # dominance refinement: if b is a running max over a, then a - b <= 0
     a_var = eqn.invars[0]
-    if not isinstance(a_var, jax.core.Literal) and a_var in b.dominates:
+    if not isinstance(a_var, jex_core.Literal) and a_var in b.dominates:
         out = Interval(min(out.lo, 0.0), min(out.hi, 0.0),
                        int_valued=out.int_valued, reduced=out.reduced)
     self._land(env, eqn, eqn.outvars[0], out)
@@ -457,7 +458,7 @@ def _rem(self, env, eqn):
 def _max(self, env, eqn):
     a, b = _in(self, env, eqn)
     dominated = frozenset(
-        v for v in eqn.invars if not isinstance(v, jax.core.Literal))
+        v for v in eqn.invars if not isinstance(v, jex_core.Literal))
     self._land(env, eqn, eqn.outvars[0], domain.max_(a, b, dominated))
 
 
@@ -730,7 +731,7 @@ def _reduce_sum(self, env, eqn):
 def _reduce_max(self, env, eqn):
     (a,) = _in(self, env, eqn)
     dominated = frozenset(
-        v for v in eqn.invars if not isinstance(v, jax.core.Literal))
+        v for v in eqn.invars if not isinstance(v, jex_core.Literal))
     self._land(env, eqn, eqn.outvars[0],
                a.with_(dominates=a.dominates | dominated))
 
@@ -814,7 +815,7 @@ def _gather(self, env, eqn):
 # -- control flow ----------------------------------------------------
 
 
-@_register("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
+@_register("jit", "closed_call", "custom_jvp_call", "custom_vjp_call",
            "custom_vjp_call_jaxpr", "remat", "checkpoint", "core_call")
 def _call(self, env, eqn):
     params = eqn.params

@@ -15,6 +15,7 @@ import math
 from typing import Any, Callable, Sequence
 
 import jax
+from jax.extend import core as jex_core
 import jax.numpy as jnp
 
 
@@ -82,7 +83,7 @@ class TraceSpec:
     # Why each output contract holds/matters, for findings (optional).
     out_contract_reason: str = ""
 
-    def trace(self) -> jax.core.ClosedJaxpr:
+    def trace(self) -> jex_core.ClosedJaxpr:
         return jax.make_jaxpr(self.fn)(*self.args)
 
     def input_ranges(self) -> list[ValueRange]:

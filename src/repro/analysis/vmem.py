@@ -27,6 +27,7 @@ import math
 from typing import Any
 
 import jax
+from jax.extend import core as jex_core
 import numpy as np
 
 __all__ = [
@@ -47,7 +48,8 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 # tiles materialized beside the blocks.  Chosen as a small superset of
 # the traced peak liveness (tests pin traced <= modeled).
 _SEQMUL_LIVE_CUBES = 8  # a3/b3 broadcasts + recurrence state words
-_LUT_LIVE_CUBES = 4  # idx cube + gathered products + sign cube
+_LUT_LIVE_ROW_PLANES = 8  # (bm, W)-wide: iota, one-hot, byte rows, stacked lhs
+_LUT_LIVE_COL_PLANES = 4  # (W, bn)-wide: iota, one-hot (f32 + bf16), partial dot
 _PACKED_LIVE_PLANES = 6  # even/odd lanes of both operands + partials
 _MXU_LIVE_PLANES = 4  # two dot partials + accumulator temps
 _DEFAULT_RANK = 8  # lowrank embedding rank (ApproxConfig default)
@@ -89,9 +91,11 @@ def tile_footprint(mode: str, n: int, t: int, tiles: tuple) -> FootprintReport:
         blocks = (operands + out) * 4
         transient = _SEQMUL_LIVE_CUBES * _cube(bm, bn, bk)
     elif mode == "bitexact":
-        lut = (4 ** n) * 4  # (2^n, 2^n) product table pinned whole
-        blocks = (operands + out) * 4 + lut
-        transient = _LUT_LIVE_CUBES * _cube(bm, bn, bk)
+        w = max(1 << n, 128)  # one-hot width: table rows padded to a lane tile
+        table = w * 2 * w * 2  # (W, 2W) bf16 byte planes pinned whole
+        blocks = (operands + 2 * out) * 4 + table  # out: hi and lo byte sums
+        transient = (_LUT_LIVE_ROW_PLANES * 2 * bm * w
+                     + _LUT_LIVE_COL_PLANES * w * bn) * 4
     elif mode == "lowrank":
         r = _DEFAULT_RANK
         blocks = (bm * bk + bk * bn + bm * bk * r + bk * r * bn + out) * 4
@@ -164,15 +168,15 @@ def _is_ref(var: Any) -> bool:
 def _inner_jaxprs(eqn: Any) -> list[Any]:
     out = []
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             out.append(v.jaxpr)
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             out.append(v)
         elif isinstance(v, (tuple, list)):
             for e in v:
-                if isinstance(e, jax.core.ClosedJaxpr):
+                if isinstance(e, jex_core.ClosedJaxpr):
                     out.append(e.jaxpr)
-                elif isinstance(e, jax.core.Jaxpr):
+                elif isinstance(e, jex_core.Jaxpr):
                     out.append(e)
     return out
 
@@ -180,7 +184,7 @@ def _inner_jaxprs(eqn: Any) -> list[Any]:
 def peak_live_bytes(jaxpr: Any, *, count_inputs: bool = True) -> int:
     """Peak of live non-ref intermediate bytes over a linear walk.
 
-    Sub-jaxprs (scan/cond bodies, pjit calls) contribute their own peak
+    Sub-jaxprs (scan/cond bodies, jit calls) contribute their own peak
     on top of the live set at their call point — with their *inputs*
     excluded, since a call operand is the caller's buffer and is already
     counted in the caller's live set (it stays live through the call
@@ -190,10 +194,10 @@ def peak_live_bytes(jaxpr: Any, *, count_inputs: bool = True) -> int:
     last_use: dict[Any, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for a in eqn.invars:
-            if isinstance(a, jax.core.Var):
+            if isinstance(a, jex_core.Var):
                 last_use[a] = i
     for v in jaxpr.outvars:
-        if isinstance(v, jax.core.Var):
+        if isinstance(v, jex_core.Var):
             last_use[v] = len(jaxpr.eqns)
 
     live: dict[Any, int] = {}
@@ -212,7 +216,7 @@ def peak_live_bytes(jaxpr: Any, *, count_inputs: bool = True) -> int:
                 live[v] = _aval_bytes(v.aval)
         peak = max(peak, sum(live.values()) + inner_peak)
         for a in list(eqn.invars) + list(eqn.outvars):
-            if isinstance(a, jax.core.Var) and last_use.get(a, math.inf) <= i:
+            if isinstance(a, jex_core.Var) and last_use.get(a, math.inf) <= i:
                 live.pop(a, None)
     return peak
 
@@ -225,7 +229,7 @@ def _walk_pallas(jaxpr: Any, found: list[Any]) -> None:
             _walk_pallas(inner, found)
 
 
-def estimate_pallas_calls(closed: jax.core.ClosedJaxpr) -> list[dict]:
+def estimate_pallas_calls(closed: jex_core.ClosedJaxpr) -> list[dict]:
     """Measured VMEM estimate for every ``pallas_call`` in a trace."""
     eqns: list[Any] = []
     _walk_pallas(closed.jaxpr, eqns)
@@ -235,9 +239,7 @@ def estimate_pallas_calls(closed: jax.core.ClosedJaxpr) -> list[dict]:
         kernel = eqn.params["jaxpr"]
         block_bytes = 0
         for bm_ in gm.block_mappings:
-            shape = tuple(int(d) for d in bm_.block_shape)
-            dtype = bm_.array_shape_dtype.dtype
-            block_bytes += int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
+            block_bytes += _aval_bytes(bm_.transformed_block_aval)
         live = peak_live_bytes(kernel)
         total = 2 * block_bytes + live
         reports.append({

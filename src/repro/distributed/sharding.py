@@ -33,52 +33,23 @@ __all__ = [
 
 
 def ambient_mesh():
-    """The mesh the current trace runs under, or None — across jax versions.
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh`` (set by
-    ``jax.sharding.set_mesh``/``use_mesh``); older releases (< 0.5) only
-    have the thread-local physical mesh installed by ``with mesh:``.
-    Every rule in this module degrades to a no-op when this returns None,
-    so the same model code runs on one CPU device and on the production
-    mesh regardless of the installed jax.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        try:
-            m = get()
-        except Exception:
-            m = None
-        if m is not None and not getattr(m, "empty", True):
-            return m
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if pm is None or pm.empty:
-        return None
-    return pm
+    """The mesh installed by :func:`mesh_context` around the current
+    trace, or None.  Every rule in this module is a no-op when this
+    returns None, so the same model code runs on one device and on a
+    mesh."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def mesh_context(mesh):
-    """Context manager installing ``mesh`` for the duration of a trace.
-
-    ``jax.sharding.set_mesh`` where available, the legacy ``with mesh:``
-    resource-env context otherwise.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` for the duration of a trace."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_auto_mesh(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_parallel_mesh(batch_size: Optional[int] = None, *, devices=None):
@@ -105,28 +76,16 @@ def data_parallel_mesh(batch_size: Optional[int] = None, *, devices=None):
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions, replication checks off.
-
-    Newer jax spells it ``jax.shard_map(..., check_vma=False)``; older
-    releases have ``jax.experimental.shard_map.shard_map(...,
-    check_rep=False)``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-        except TypeError:  # intermediate releases: check_rep spelling on jax.shard_map
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm  # jax < 0.6
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def mesh_axis_sizes(mesh=None) -> dict:
     m = mesh or ambient_mesh()
     if m is None:
         return {}
-    return dict(zip(m.axis_names, m.axis_sizes if hasattr(m, "axis_sizes") else m.shape.values()))
+    return dict(zip(m.axis_names, m.axis_sizes))
 
 
 def _resolve_entry(entry, dim: int, sizes: dict) -> Optional[object]:
@@ -163,10 +122,7 @@ def constrain(x: jax.Array, *spec) -> jax.Array:
     if m is None:
         return x
     sizes = mesh_axis_sizes(m)
-    resolved = resolve_spec(tuple(spec), x.shape, sizes)
-    if isinstance(m, jax.sharding.Mesh):  # concrete mesh (legacy `with mesh:` path)
-        return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(m, resolved))
-    return jax.lax.with_sharding_constraint(x, resolved)
+    return jax.lax.with_sharding_constraint(x, resolve_spec(tuple(spec), x.shape, sizes))
 
 
 # ---------------------------------------------------------------------------

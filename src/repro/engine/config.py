@@ -257,19 +257,24 @@ class KernelTiles:
     bk: int
 
 
-# VMEM sizing (machine-checked: every selection below must pass
-# repro.analysis.vmem.validate_tiles — positive, power-of-two, and the
-# closed-form footprint under budget; `launch/analyze.py --report`
-# emits the traced numbers that docs/kernels.md is generated from):
-#  * seqmul keeps ~8 live uint32 (BM, BK, BN) cubes -> cube edge 32
-#    (~1 MiB live) fits every n; n <= 4 halves the LUT-free live set so
-#    a 64-edge cube (~8 MiB live) still fits and shrinks the grid 8x.
-#  * lut pins the (2^n, 2^n) table (256 KiB at n=8) + the (BM, BK, BN)
-#    gather cube -> 64 tiles (~6 MiB live worst case).
+# Tile sizing.  The chip's compiler takes a block whose last two
+# extents are multiples of the (8, 128) vreg tile (sublanes, lanes), so
+# every K extent below is a lane multiple and every M extent a sublane
+# multiple; all but lowrank clamp ``bm`` to the row count rounded up to 8
+# (``repro.kernels.row_block``), so a decode step's few rows are not
+# padded to a prefill-sized block.
+# Each selection must also pass repro.analysis.vmem.validate_tiles
+# (positive, power-of-two, closed-form footprint under budget;
+# `launch/analyze.py --report` emits the traced numbers that
+# docs/kernels.md is generated from):
+#  * seqmul keeps ~8 live uint32 (BM, BK, BN) cubes -> (8, 128, 128),
+#    ~4 MiB live, for every n <= 12.
+#  * lut selects table rows and columns by one-hot MXU dots, one K index
+#    at a time: the (W, 2W) bf16 byte planes plus a few (BM, W) and
+#    (W, BN) planes per step, ~4 MiB at (128, 256, 128).
 #  * lowrank/packed are pure MXU dot kernels -> 128 tiles.
-_SEQMUL_TILES_SMALL_N = KernelTiles(bm=64, bn=64, bk=64)
-_SEQMUL_TILES = KernelTiles(bm=32, bn=32, bk=32)
-_LUT_TILES = KernelTiles(bm=64, bn=64, bk=64)
+_SEQMUL_TILES = KernelTiles(bm=8, bn=128, bk=128)
+_LUT_TILES = KernelTiles(bm=128, bn=256, bk=128)
 _MXU_TILES = KernelTiles(bm=128, bn=128, bk=128)
 
 
@@ -289,7 +294,7 @@ def kernel_tiles(mode: str, n: int, t: int) -> KernelTiles:
     (mode, n, t) — at resolution time, not inside Pallas lowering.
     """
     if mode == "seqmul":
-        tiles = _SEQMUL_TILES_SMALL_N if n <= 4 else _SEQMUL_TILES
+        tiles = _SEQMUL_TILES
     elif mode == "bitexact":
         tiles = _LUT_TILES
     else:

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.engine.policy import resolve_interpret
+
 __all__ = ["flash_attention", "flash_decode"]
 
 NEG_INF = -2.3819763e38
@@ -270,23 +272,27 @@ def flash_attention(
     scale: float = 1.0,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """q (B,S,H,hd), k/v (B,T,KV,hd), positions (B,S)/(B,T) -> (B,S,H,hd) f32."""
+    """q (B,S,H,hd), k/v (B,T,KV,hd), positions (B,S)/(B,T) -> (B,S,H,hd) f32.
+
+    ``interpret=None`` resolves through the engine's shared backend policy.
+    """
     o, _ = _fwd(q, k, v, q_pos, k_pos, causal, window, softcap, scale, bq, bk,
-                interpret)
+                resolve_interpret(interpret))
     return o
 
 
 def _fwd_vjp(q, k, v, q_pos, k_pos, causal, window, softcap, scale, bq, bk,
              interpret):
     o, lse = _fwd(q, k, v, q_pos, k_pos, causal, window, softcap, scale, bq, bk,
-                  interpret)
+                  resolve_interpret(interpret))
     return o, (q, k, v, q_pos, k_pos, o, lse)
 
 
 def _bwd_vjp(causal, window, softcap, scale, bq, bk, interpret, res, do):
-    return _bwd(causal, window, softcap, scale, bq, bk, interpret, res, do)
+    return _bwd(causal, window, softcap, scale, bq, bk,
+                resolve_interpret(interpret), res, do)
 
 
 flash_attention.defvjp(_fwd_vjp, _bwd_vjp)
@@ -342,13 +348,14 @@ def flash_decode(
     softcap: Optional[float] = None,
     scale: float = 1.0,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Decode-step attention with the KV cache streamed through VMEM.
 
     The grid iterates (batch, kv-head, key-block); each kv head's g query
     heads form the row dim of the MXU tile, so GQA needs no HBM repeat.
-    Returns (B, H, hd) f32.
+    Returns (B, H, hd) f32.  ``interpret=None`` resolves through the
+    engine's shared backend policy.
     """
     b, h, hd = q.shape
     t, kv = k.shape[1], k.shape[2]
@@ -376,6 +383,6 @@ def flash_decode(
             jax.ShapeDtypeStruct((b, kv, g, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, 2, kv, g), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_pos, k_pos, qg, k, v)
     return o.reshape(b, h, hd)

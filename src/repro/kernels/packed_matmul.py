@@ -30,12 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.engine.policy import resolve_interpret
+from repro.kernels import row_block
 
 __all__ = ["pack_i16_pairs", "packed_matmul_pallas", "DEFAULT_BM", "DEFAULT_BN", "DEFAULT_BK"]
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BK = 64  # packed words: 64 u32 = 128 int16 K-lanes per tile
+DEFAULT_BK = 128  # packed words: 128 u32 = 256 int16 K-lanes per tile
 
 
 def pack_i16_pairs(q: jax.Array, *, axis: int) -> jax.Array:
@@ -88,6 +89,7 @@ def _packed_matmul_jit(
     m_dim, kp_dim = pa.shape
     kp2, n_dim = pb.shape
     assert kp_dim == kp2, (pa.shape, pb.shape)
+    bm = row_block(bm, m_dim)
 
     def pad2(x, r, c):
         return jnp.pad(jnp.asarray(x, jnp.uint32), ((0, -x.shape[0] % r), (0, -x.shape[1] % c)))

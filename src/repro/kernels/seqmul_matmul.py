@@ -23,9 +23,9 @@ stay integer-valued in f32 for n <= 12 and K within the tested range
 (|sum| < 2^24), so the tile reduction order cannot perturb the result —
 asserted against the reference oracle in ``tests/test_fused_kernels.py``.
 
-VMEM budget: the recurrence keeps ~6 live uint32 cubes of shape
-(BM, BK, BN); the default 32³ tiles put that at ~768 KiB, well under the
-~16 MiB/core budget (see docs/kernels.md for the sizing table).  Tile
+VMEM budget: the recurrence keeps ~8 live uint32 cubes of shape
+(BM, BK, BN); the default (8, 128, 128) tiles put that at ~4 MiB, under
+the ~16 MiB/core budget (see docs/kernels.md for the sizing table).  Tile
 sizes are resolved per call by ``engine.config.kernel_tiles`` so quality
 tiers can trade tile footprint against grid overhead.
 """
@@ -40,14 +40,16 @@ from jax.experimental import pallas as pl
 
 from repro.engine.policy import resolve_interpret
 from repro.engine.recurrence import seqmul_recurrence, validate_nt
+from repro.kernels import row_block
 
 __all__ = ["seqmul_matmul_pallas", "DEFAULT_BM", "DEFAULT_BN", "DEFAULT_BK"]
 
-# 32^3 u32 cube = 128 KiB per live recurrence word (~6 live) — comfortably
-# inside VMEM while keeping the grid coarse enough to amortize dispatch.
-DEFAULT_BM = 32
-DEFAULT_BN = 32
-DEFAULT_BK = 32
+# (8, 128, 128) u32 cube = 512 KiB per live recurrence word (~8 live):
+# inside VMEM, with K and N extents that are lane multiples as the chip's
+# compiler requires of a block's last dimension.
+DEFAULT_BM = 8
+DEFAULT_BN = 128
+DEFAULT_BK = 128
 
 
 def _kernel(ma_ref, sa_ref, mb_ref, sb_ref, o_ref, *, n, t, approx, fix_to_1):
@@ -68,9 +70,14 @@ def _kernel(ma_ref, sa_ref, mb_ref, sb_ref, o_ref, *, n, t, approx, fix_to_1):
     lo, s_lsp, s_msp, _ = seqmul_recurrence(
         a3, b3, n=n, t=t, approx=approx, fix_to_1=fix_to_1
     )
-    # assemble the 2n-bit product value in f32 (exact for n <= 12)
-    prod = lo.astype(jnp.float32) + jnp.float32(1 << (n - 1)) * (
-        s_lsp.astype(jnp.float32) + jnp.float32(1 << t) * s_msp.astype(jnp.float32)
+    # assemble the 2n-bit product value in f32 (exact for n <= 12); the
+    # words go through int32 first — they are below 2^24, and Mosaic has
+    # no uint32 -> float32 conversion
+    def f32(x):
+        return x.astype(jnp.int32).astype(jnp.float32)
+
+    prod = f32(lo) + jnp.float32(1 << (n - 1)) * (
+        f32(s_lsp) + jnp.float32(1 << t) * f32(s_msp)
     )
     signs = sa_ref[...][:, :, None] * sb_ref[...][None, :, :]
     o_ref[...] += (prod * signs).sum(axis=1)
@@ -98,6 +105,7 @@ def _seqmul_matmul_jit(
     m_dim, k_dim = mag_a.shape
     k2, n_dim = mag_b.shape
     assert k_dim == k2, (mag_a.shape, mag_b.shape)
+    bm = row_block(bm, m_dim)
 
     def pad2(x, r, c, dt):
         x = jnp.asarray(x, dt)

@@ -37,6 +37,7 @@ from repro.distributed.sharding import data_parallel_mesh
 from repro.engine import config as engine_config
 from repro.engine import modes as engine_modes
 from repro.models.registry import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import (
     SelfSpeculative,
     ServeStats,
@@ -149,6 +150,7 @@ def main(argv=None) -> None:
                     help="speculative: tier whose engine verifies (default: "
                          "the pool's own tier)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

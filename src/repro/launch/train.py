@@ -30,6 +30,7 @@ from repro.configs.registry import apply_approx, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.engine import modes as engine_modes
 from repro.models.registry import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import FailureInjector, StragglerMonitor, run_loop
 from repro.train.steps import init_train_state, make_train_step
 
@@ -64,6 +65,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out", default=None, help="write metrics history JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

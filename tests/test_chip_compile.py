@@ -1,0 +1,89 @@
+"""The main-path GEMM kernels compile natively for a TPU v5e chip.
+
+Interpret mode cannot show the chip's compiler refusing a block that is
+out of the (8, 128) tiling or a lowering Mosaic does not have.  These
+tests compile each fused GEMM kernel with ``interpret=False`` for a
+described (not attached) ``v5e:2x2`` topology, at qwen3-0.6b's MLP shapes
+with the tiles ``engine.config.kernel_tiles`` hands out, and read the
+compiled program's memory analysis.  Nothing runs, so they say nothing
+about results or speed.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.engine import config as engine_config
+
+ROWS = (8, 128)  # a decode batch and a prompt bucket
+MLP_SHAPES = ((1024, 3072), (3072, 1024))  # (K, N) of w1/w3 and w2
+N_BITS, T_SPLIT, RANK = 8, 4, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _operands(kernel, m, k, n):
+    """(jitted kernel body with its static arguments, operand shapes)."""
+    from repro.kernels import lowrank_matmul, lut_matmul, packed_matmul, seqmul_matmul
+
+    mode = {"lut": "bitexact", "seqmul": "seqmul", "packed": "inject",
+            "lowrank": "lowrank"}[kernel]
+    tiles = engine_config.kernel_tiles(mode, N_BITS, T_SPLIT)
+    kw = dict(bm=tiles.bm, bn=tiles.bn, bk=tiles.bk, interpret=False)
+    u32, f32 = jnp.uint32, jnp.float32
+    if kernel == "lut":
+        fn = functools.partial(lut_matmul._lut_matmul_jit, n=N_BITS, **kw)
+        shapes = [((1 << 2 * N_BITS,), jnp.int32), ((m, k), u32), ((m, k), f32),
+                  ((k, n), u32), ((k, n), f32)]
+    elif kernel == "seqmul":
+        fn = functools.partial(seqmul_matmul._seqmul_matmul_jit, n=N_BITS, t=T_SPLIT,
+                               approx=True, fix_to_1=True, **kw)
+        shapes = [((m, k), u32), ((m, k), f32), ((k, n), u32), ((k, n), f32)]
+    elif kernel == "packed":
+        fn = functools.partial(packed_matmul._packed_matmul_jit, **kw)
+        shapes = [((m, k // 2), u32), ((k // 2, n), u32)]
+    else:
+        fn = functools.partial(lowrank_matmul._lowrank_matmul_jit, rank=RANK, **kw)
+        shapes = [((m, k), f32), ((k, n), f32), ((m, k, RANK), f32), ((k, n, RANK), f32)]
+    return fn, shapes
+
+
+@pytest.mark.parametrize("shape", MLP_SHAPES, ids=lambda s: f"K{s[0]}xN{s[1]}")
+@pytest.mark.parametrize("m", ROWS, ids=lambda m: f"M{m}")
+@pytest.mark.parametrize("kernel", ["lut", "seqmul", "packed", "lowrank"])
+def test_kernel_compiles_for_v5e(one_chip, kernel, m, shape):
+    k, n = shape
+    fn, shapes = _operands(kernel, m, k, n)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel was not lowered"
+    mem = compiled.memory_analysis()
+    out_bytes = m * n * 4
+    assert mem.output_size_in_bytes == out_bytes
+    # padding and operand re-layout stay small beside the operands
+    assert mem.temp_size_in_bytes <= 4 * mem.argument_size_in_bytes

@@ -96,3 +96,29 @@ def test_bf16_inputs():
                           scale, 32, 32, True)
     want = _oracle(q, k, v, qp, kp, causal=True, window=None, softcap=None, scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "flash_decode"])
+def test_omitted_interpret_follows_engine_policy(kernel, monkeypatch):
+    """With ``interpret`` omitted the kernels resolve it through
+    ``engine.policy``: interpret mode when forced, native lowering (which
+    the CPU refuses) when native is forced — never a silent default."""
+    from repro.kernels.flash_attention import flash_decode
+
+    q, k, v, qp, kp = _inputs(s=1 if kernel == "flash_decode" else 32, t=32)
+    scale = q.shape[-1] ** -0.5
+    if kernel == "flash_decode":
+        def run():
+            return flash_decode(q[:, 0], k, v, qp[:, 0], kp, scale=scale, bk=16)
+        want = _oracle(q, k, v, qp, kp, causal=True, window=None, softcap=None,
+                       scale=scale)[:, 0]
+    else:
+        def run():
+            return flash_attention(q, k, v, qp, kp, True, None, None, scale, 16, 16)
+        want = _oracle(q, k, v, qp, kp, causal=True, window=None, softcap=None, scale=scale)
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    np.testing.assert_allclose(np.asarray(run()), np.asarray(want), rtol=2e-5, atol=2e-5)
+    if jax.default_backend() == "cpu":
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+        with pytest.raises(Exception):
+            run()
