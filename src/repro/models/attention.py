@@ -99,11 +99,12 @@ def _allow(q_pos, k_pos, *, causal: bool, window: Optional[int]):
 
 def _scores(q, k, softcap, scale):
     # q: (B, Sq, H, hd), k: (B, Sk, H, hd) -> (B, H, Sq, Sk)
-    s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32), k.astype(jnp.float32))
-    s *= scale
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
-    return s
+    with jax.named_scope("scores"):
+        s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32), k.astype(jnp.float32))
+        s *= scale
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        return s
 
 
 def _attend_direct(q, k, v, q_pos, k_pos, *, causal, window, softcap, scale):
@@ -210,17 +211,18 @@ def attention(
     decode = s == 1 and cache is not None
     per_row = cache_pos is not None and getattr(cache_pos, "ndim", 0) >= 1
     if cache is not None and kv_x is None:
-        if per_row:
-            starts = jnp.asarray(cache_pos, jnp.int32)
-            kfull = _row_update(cache.k, k.astype(cache.k.dtype), starts)
-            vfull = _row_update(cache.v, v.astype(cache.v.dtype), starts)
-        else:
-            kfull = jax.lax.dynamic_update_slice(
-                cache.k, k.astype(cache.k.dtype), (0, cache_pos, 0, 0)
-            )
-            vfull = jax.lax.dynamic_update_slice(
-                cache.v, v.astype(cache.v.dtype), (0, cache_pos, 0, 0)
-            )
+        with jax.named_scope("cache_update"):
+            if per_row:
+                starts = jnp.asarray(cache_pos, jnp.int32)
+                kfull = _row_update(cache.k, k.astype(cache.k.dtype), starts)
+                vfull = _row_update(cache.v, v.astype(cache.v.dtype), starts)
+            else:
+                kfull = jax.lax.dynamic_update_slice(
+                    cache.k, k.astype(cache.k.dtype), (0, cache_pos, 0, 0)
+                )
+                vfull = jax.lax.dynamic_update_slice(
+                    cache.v, v.astype(cache.v.dtype), (0, cache_pos, 0, 0)
+                )
         if decode:  # flash-decode: shard the cache sequence axis over TP
             kfull = constrain(kfull, DP, TP, None, None)
             vfull = constrain(vfull, DP, TP, None, None)
@@ -312,8 +314,9 @@ def attention(
     else:
         # GQA: repeat kv to the flat head axis (cache stays unrepeated)
         if g > 1:
-            k = jnp.repeat(k, g, axis=2)
-            v = jnp.repeat(v, g, axis=2)
+            with jax.named_scope("gqa_repeat"):
+                k = jnp.repeat(k, g, axis=2)
+                v = jnp.repeat(v, g, axis=2)
         if not decode:
             k = constrain(k, DP, None, TP, None)
             v = constrain(v, DP, None, TP, None)

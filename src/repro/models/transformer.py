@@ -83,10 +83,11 @@ def _apply_block(
     cfg = ctx.cfg
     h = layers.rms_norm(x, params["ln1"], cfg.norm_eps)
     if kind in ("attn_global", "attn_local"):
-        out, new_cache = attention.attention(
-            params["attn"], h, positions, ctx,
-            local=(kind == "attn_local"), cache=cache, cache_pos=cache_pos,
-        )
+        with jax.named_scope("attn"):
+            out, new_cache = attention.attention(
+                params["attn"], h, positions, ctx,
+                local=(kind == "attn_local"), cache=cache, cache_pos=cache_pos,
+            )
     elif kind == "rglru":
         out, new_cache = rglru.rglru_block(params["rglru"], h, ctx, cache=cache)
     elif kind == "ssd":
@@ -102,7 +103,8 @@ def _apply_block(
         if cfg.num_experts > 0:
             out2, aux = moe.moe_ffn(params["ffn_moe"], h2, ctx)
         else:
-            out2 = layers.mlp(params["ffn"], h2, ctx)
+            with jax.named_scope("mlp"):
+                out2 = layers.mlp(params["ffn"], h2, ctx)
         if cfg.use_post_norm:
             out2 = layers.rms_norm(out2, params["post_ln2"], cfg.norm_eps)
         x = x + out2

@@ -81,6 +81,7 @@ class RequestStats:
     re-based to the request's *arrival* time — what the client
     experiences, queueing included — and ``queue_delay_s`` separates the
     waiting component out (``ttft_s = queue_delay_s + admission cost``).
+    ``token_s`` stamps every token on the run clock in both loops.
     """
 
     id: int
@@ -96,6 +97,9 @@ class RequestStats:
     slo_ttft_s: Optional[float] = None  # the request's TTFT SLO, if any
     proposed: int = 0  # speculative rounds: draft tokens proposed for this row
     accepted: int = 0  # of those, accepted by the verify forward
+    # each token's stamp on the run clock (seconds from run start), in
+    # order; length tokens_out (empty for old readers and the static loop)
+    token_s: tuple = ()
 
     @property
     def rolled_back(self) -> int:

@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.serve.policy import AdmissionPolicy, LoadSnapshot, StaticTier, get_policy
 from repro.serve.request import Request, RequestStats
-from repro.serve.stats import ServeResult, ServeStats, SlotAccounting
+from repro.serve.stats import ServeResult, ServeStats, SlotAccounting, SpanTotals, span
 from repro.serve.strategy import RowView, TierEngine, build_tier_engine, get_strategy
 from repro.train.steps import make_decode_step, make_prefill_step
 
@@ -178,8 +178,6 @@ class _Slot:
     req: Request
     tokens: list  # generated token ids (first from admission prefill)
     admit_step: int
-    t_first: float  # clock at first token (perf_counter closed loop)
-    t_done: float = 0.0
     done: bool = False
     finish_reason: str = ""
     arrival_s: float = 0.0  # open loop: arrival time on the run clock
@@ -187,21 +185,23 @@ class _Slot:
     tier_served: str = ""  # accuracy tier at admission ("" = pool config)
     proposed: int = 0  # speculative: draft tokens proposed for this row
     accepted: int = 0  # speculative: draft tokens the verify step accepted
+    # run clock (seconds from run start) of each token: the first is the
+    # time to first token, the last the retirement once done
+    stamps: list = dataclasses.field(default_factory=list)
 
     @property
     def emitted(self) -> int:
         return len(self.tokens)
 
-    def absorb(self, tok: int, now: Optional[float] = None) -> None:
-        """Take one token; ``now`` stamps completion on the open-loop
-        clock (closed loop keeps the legacy perf_counter stamp)."""
+    def absorb(self, tok: int, now: float) -> None:
+        """Take one token, stamped ``now`` on the run clock (the open
+        loop's clock, or perf_counter - t0)."""
         self.tokens.append(tok)
+        self.stamps.append(now)
         if self.req.eos_id is not None and tok == self.req.eos_id:
             self.done, self.finish_reason = True, "eos"
         elif self.emitted >= self.req.max_new:
             self.done, self.finish_reason = True, "budget"
-        if self.done:
-            self.t_done = time.perf_counter() if now is None else now
 
 
 class ContinuousScheduler:
@@ -331,12 +331,14 @@ class ContinuousScheduler:
     def _prefill_row(self, req: Request, caches: dict, row: int, engine=None):
         """Fused admission: single-row prefill + scatter; returns (caches, tok0)."""
         eng = engine if engine is not None else self._base_engine
-        toks, pos = self._pad(req)
-        caches, tok0 = eng.admit_step(
-            self.params, caches, jnp.asarray(toks[None]), jnp.asarray(pos[None]),
-            jnp.int32(row),
-        )
-        return caches, int(np.asarray(tok0))
+        with span("admit.dispatch"):
+            toks, pos = self._pad(req)
+            caches, tok0 = eng.admit_step(
+                self.params, caches, jnp.asarray(toks[None]),
+                jnp.asarray(pos[None]), jnp.int32(row),
+            )
+        with span("admit.sync"):
+            return caches, int(np.asarray(tok0))
 
     def warmup(self) -> None:
         """Compile the pool prefill, the admission step, and the pool decode."""
@@ -393,6 +395,11 @@ class ContinuousScheduler:
           identical timings, so queue delays, SLO attainment, and
           tier-switch sequences are reproducible and CI-gateable.
         * ``"wall"`` — real time; idle gaps are slept through.
+
+        Host spans (:func:`repro.serve.stats.span`) cover each loop
+        ``tick`` and what runs inside it; their per-run totals land in
+        ``ServeStats.spans``, and every request's per-token stamps in
+        ``RequestStats.token_s``.
 
         ``policy`` is an :class:`~repro.serve.policy.AdmissionPolicy`
         instance or registry name (``"static"``/``"slo-adaptive"``/
@@ -460,6 +467,12 @@ class ContinuousScheduler:
         now = 0.0  # open-loop clock (virtual seconds, or wall since t0)
 
         t0 = time.perf_counter()
+        spans = SpanTotals()  # host spans of this run (stats.span)
+
+        def run_clock() -> float:
+            # the run clock: the open loop's own (virtual or wall), else
+            # wall seconds since t0
+            return now if open_loop else time.perf_counter() - t0
 
         def pump() -> None:
             # open loop: requests whose arrival time has passed move from
@@ -474,7 +487,7 @@ class ContinuousScheduler:
             if open_loop and queue:
                 head_wait = now - arrived_at[queue[0].id]
             return LoadSnapshot(
-                now_s=now if open_loop else time.perf_counter() - t0,
+                now_s=run_clock(),
                 step=step,
                 queue_depth=len(queue),
                 pending=len(pending),
@@ -485,37 +498,24 @@ class ContinuousScheduler:
 
         def retire(i: int) -> None:
             s = slots[i]
-            if open_loop:
-                rs = RequestStats(
-                    id=s.req.id,
-                    prompt_len=s.req.prompt_len,
-                    tokens_out=s.emitted,
-                    admit_step=s.admit_step,
-                    # what the client experiences: both re-based to arrival
-                    ttft_s=s.t_first - s.arrival_s,
-                    latency_s=(s.t_done if s.done else now) - s.arrival_s,
-                    finish_reason=s.finish_reason,
-                    arrival_s=s.arrival_s,
-                    queue_delay_s=s.queue_delay_s,
-                    tier_served=s.tier_served,
-                    slo_ttft_s=s.req.slo_ttft_s,
-                    proposed=s.proposed,
-                    accepted=s.accepted,
-                )
-            else:
-                rs = RequestStats(
-                    id=s.req.id,
-                    prompt_len=s.req.prompt_len,
-                    tokens_out=s.emitted,
-                    admit_step=s.admit_step,
-                    ttft_s=s.t_first - t0,
-                    latency_s=(s.t_done or time.perf_counter()) - t0,
-                    finish_reason=s.finish_reason,
-                    tier_served=s.tier_served,
-                    slo_ttft_s=s.req.slo_ttft_s,
-                    proposed=s.proposed,
-                    accepted=s.accepted,
-                )
+            rs = RequestStats(
+                id=s.req.id,
+                prompt_len=s.req.prompt_len,
+                tokens_out=s.emitted,
+                admit_step=s.admit_step,
+                # what the client experiences: open loop, both re-based to
+                # arrival; closed loop, every request arrives at run start
+                ttft_s=s.stamps[0] - s.arrival_s,
+                latency_s=(s.stamps[-1] if s.done else run_clock()) - s.arrival_s,
+                finish_reason=s.finish_reason,
+                arrival_s=s.arrival_s,
+                queue_delay_s=s.queue_delay_s,
+                tier_served=s.tier_served,
+                slo_ttft_s=s.req.slo_ttft_s,
+                proposed=s.proposed,
+                accepted=s.accepted,
+                token_s=tuple(s.stamps),
+            )
             retired.append(rs)
             outputs[s.req.id] = np.asarray(s.tokens, np.int32)
             slots[i] = None
@@ -552,162 +552,174 @@ class ContinuousScheduler:
             # admission prefill wrote cache indices [0, P); the row's first
             # decode write lands at exactly P
             last_write[i] = P - 1
-            slot = _Slot(req=req, tokens=[], admit_step=step, t_first=t_first,
+            slot = _Slot(req=req, tokens=[], admit_step=step,
                          arrival_s=arrival, queue_delay_s=queue_delay,
                          tier_served=admit_eng.name or "")
-            slot.absorb(tok0, now=t_first if open_loop else None)
+            slot.absorb(tok0, t_first)
             cur_tok[i, 0] = tok0
             slots[i] = slot
             if slot.done:  # budget 1 / instant EOS: free the slot again
                 retire(i)
 
-        with self._mesh_ctx():
+        with self._mesh_ctx(), spans.active():
             if open_loop:
                 if clock == "wall":
                     now = time.perf_counter() - t0
                 pump()
-            if (
-                not open_loop
-                and len(queue) >= B
-                # only when the policy cannot shed (admit is the base
-                # always-True implementation) — a shedding policy must see
-                # every request through the per-request admission path
-                and type(pol).admit is AdmissionPolicy.admit
-            ):
-                # initial fill: the batched prefill of all B slots *is* the
-                # pool cache — one dispatch, no scatters
-                first = [queue.popleft() for _ in range(B)]
-                if pol.enforces_tier_tags:
-                    for r in first:
-                        _check_request_quality(r, self.quality)
-                padded = [self._pad(r) for r in first]
-                toks = jnp.asarray(np.stack([t for t, _ in padded]))
-                pos = jnp.asarray(np.stack([p for _, p in padded]))
-                caches, tok0s = admit_eng.prefill_pool(self.params, toks, pos)
-                tok0s = np.asarray(tok0s)
-                t_b = time.perf_counter()
-                prefill_s += t_b - t0
-                for i, req in enumerate(first):
-                    seat(i, req, int(tok0s[i]), t_b, pool=True)
-            else:
-                caches = self.model.init_caches(B, self.capacity, self._cache_dtype)
-            while True:
-                if open_loop:
-                    if clock == "wall":
-                        now = time.perf_counter() - t0
-                    pump()
-                # one control tick: the policy picks this tick's serving
-                # tier; admissions and decode below both run at it
-                want = pol.tier(snapshot())
-                want = want if want is not None else self.quality
-                if want != engine.key:
-                    engine = self.engine_for(want)
-                    admit_eng = self.engine_for(
-                        self.strategy.admission_key(engine.key))
-                # retire finished rows, refill freed slots from the queue
-                for i in range(B):
-                    if slots[i] is not None and slots[i].done:
-                        retire(i)
-                    while slots[i] is None and queue:
-                        req = queue[0]
-                        if not pol.admit(req, snapshot()):
-                            queue.popleft()
-                            reject(req)
-                            continue
-                        queue.popleft()
+            with span("tick"):  # the initial fill is a tick of its own
+                if (
+                    not open_loop
+                    and len(queue) >= B
+                    # only when the policy cannot shed (admit is the base
+                    # always-True implementation) — a shedding policy must
+                    # see every request through the per-request admission
+                    # path
+                    and type(pol).admit is AdmissionPolicy.admit
+                ):
+                    # initial fill: the batched prefill of all B slots *is*
+                    # the pool cache — one dispatch, no scatters
+                    with span("pool_prefill"):
+                        first = [queue.popleft() for _ in range(B)]
                         if pol.enforces_tier_tags:
-                            _check_request_quality(req, self.quality)
-                        t_a = time.perf_counter()
-                        caches, tok0 = self._prefill_row(req, caches, i, admit_eng)
+                            for r in first:
+                                _check_request_quality(r, self.quality)
+                        padded = [self._pad(r) for r in first]
+                        toks = jnp.asarray(np.stack([t for t, _ in padded]))
+                        pos = jnp.asarray(np.stack([p for _, p in padded]))
+                        caches, tok0s = admit_eng.prefill_pool(self.params, toks, pos)
+                        with span("pool_prefill.sync"):
+                            tok0s = np.asarray(tok0s)
                         t_b = time.perf_counter()
-                        prefill_s += t_b - t_a
+                        prefill_s += t_b - t0
+                        for i, req in enumerate(first):
+                            seat(i, req, int(tok0s[i]), t_b - t0, pool=True)
+                else:
+                    caches = self.model.init_caches(B, self.capacity, self._cache_dtype)
+            while True:
+                with span("tick"):
+                    if open_loop:
+                        if clock == "wall":
+                            now = time.perf_counter() - t0
+                        pump()
+                    # one control tick: the policy picks this tick's serving
+                    # tier; admissions and decode below both run at it
+                    with span("policy"):
+                        want = pol.tier(snapshot())
+                        want = want if want is not None else self.quality
+                        if want != engine.key:
+                            engine = self.engine_for(want)
+                            admit_eng = self.engine_for(
+                                self.strategy.admission_key(engine.key))
+                    # retire finished rows, refill freed slots from the queue
+                    for i in range(B):
+                        if slots[i] is not None and slots[i].done:
+                            retire(i)
+                        while slots[i] is None and queue:
+                            req = queue[0]
+                            if not pol.admit(req, snapshot()):
+                                queue.popleft()
+                                reject(req)
+                                continue
+                            queue.popleft()
+                            if pol.enforces_tier_tags:
+                                _check_request_quality(req, self.quality)
+                            with span("admit"):
+                                t_a = time.perf_counter()
+                                caches, tok0 = self._prefill_row(req, caches, i, admit_eng)
+                                t_b = time.perf_counter()
+                                prefill_s += t_b - t_a
+                                if open_loop:
+                                    arr = arrived_at.pop(req.id)
+                                    qd = now - arr
+                                    now = (
+                                        now + step_time_s * admit_eng.cost_factor
+                                        if clock == "virtual"
+                                        else time.perf_counter() - t0
+                                    )
+                                    seat(i, req, tok0, now, arrival=arr, queue_delay=qd)
+                                    pump()  # admission took time: new arrivals?
+                                else:
+                                    seat(i, req, tok0, t_b - t0)
+
+                    live = [i for i in range(B) if slots[i] is not None]
+                    if not live:
+                        if open_loop and pending:
+                            # idle gap: nothing decoding, nothing admissible —
+                            # jump (or sleep) the clock to the next arrival
+                            with span("idle"):
+                                nxt_arrival = pending[0][1]
+                                if clock == "virtual":
+                                    now = max(now, nxt_arrival)
+                                else:
+                                    wait = nxt_arrival - (time.perf_counter() - t0)
+                                    if wait > 0:
+                                        time.sleep(wait)
+                                    now = time.perf_counter() - t0
+                            pump()
+                            continue
+                        break
+                    max_live = max(max_live, len(live))
+
+                    # one decode round, delegated to the pool's strategy:
+                    # greedy is exactly the historical single decode;
+                    # speculative is k draft steps + one batched verify forward
+                    rows = [
+                        RowView(index=i, prompt_len=slots[i].req.prompt_len,
+                                emitted=slots[i].emitted,
+                                strategy=slots[i].req.strategy)
+                        for i in live
+                    ]
+                    with span("decode"):
+                        t_d = time.perf_counter()
+                        rr = self.strategy.decode_round(
+                            self, engine, caches, cur_tok, rows,
+                            speculate=pol.speculation(snapshot()),
+                        )
+                        decode_s += time.perf_counter() - t_d
+                    caches = rr.caches
+                    step += rr.steps
+                    busy_row_steps += len(live) * rr.steps
+                    modeled_cost += rr.cost
+                    spec_proposed += rr.proposed
+                    spec_accepted += rr.accepted
+                    if rr.proposed:
+                        spec_rounds += 1
+                    with span("absorb"):
                         if open_loop:
-                            arr = arrived_at.pop(req.id)
-                            qd = now - arr
                             now = (
-                                now + step_time_s * admit_eng.cost_factor
+                                now + step_time_s * rr.cost
                                 if clock == "virtual"
                                 else time.perf_counter() - t0
                             )
-                            seat(i, req, tok0, now, arrival=arr, queue_delay=qd)
-                            pump()  # admission took time: new arrivals?
-                        else:
-                            seat(i, req, tok0, t_b)
-
-                live = [i for i in range(B) if slots[i] is not None]
-                if not live:
-                    if open_loop and pending:
-                        # idle gap: nothing decoding, nothing admissible —
-                        # jump (or sleep) the clock to the next arrival
-                        nxt_arrival = pending[0][1]
-                        if clock == "virtual":
-                            now = max(now, nxt_arrival)
-                        else:
-                            wait = nxt_arrival - (time.perf_counter() - t0)
-                            if wait > 0:
-                                time.sleep(wait)
-                            now = time.perf_counter() - t0
-                        pump()
-                        continue
-                    break
-                max_live = max(max_live, len(live))
-
-                # one decode round, delegated to the pool's strategy: greedy
-                # is exactly the historical single decode; speculative is k
-                # draft steps + one batched verify forward
-                rows = [
-                    RowView(index=i, prompt_len=slots[i].req.prompt_len,
-                            emitted=slots[i].emitted,
-                            strategy=slots[i].req.strategy)
-                    for i in live
-                ]
-                t_d = time.perf_counter()
-                rr = self.strategy.decode_round(
-                    self, engine, caches, cur_tok, rows,
-                    speculate=pol.speculation(snapshot()),
-                )
-                caches = rr.caches
-                decode_s += time.perf_counter() - t_d
-                step += rr.steps
-                busy_row_steps += len(live) * rr.steps
-                modeled_cost += rr.cost
-                spec_proposed += rr.proposed
-                spec_accepted += rr.accepted
-                if rr.proposed:
-                    spec_rounds += 1
-                if open_loop:
-                    now = (
-                        now + step_time_s * rr.cost
-                        if clock == "virtual"
-                        else time.perf_counter() - t0
-                    )
-                for i in live:
-                    s = slots[i]
-                    pr = rr.per_row.get(i)
-                    if pr is not None:
-                        s.proposed += pr[0]
-                        s.accepted += pr[1]
-                    for tok in rr.tokens.get(i, ()):
-                        if s.done:  # budget/EOS cut the committed run short
-                            break
-                        # per committed token the same invariants the
-                        # pre-strategy loop checked per step: the physical
-                        # write index advances by exactly one slot, stays
-                        # inside the logical window, and the true position
-                        # is the write index shifted by the row's pad offset
-                        wr = P + s.emitted - 1
-                        pp = s.req.prompt_len + s.emitted - 1
-                        if (
-                            wr != last_write[i] + 1
-                            or wr >= P + self.max_new
-                            or pp != wr - (P - s.req.prompt_len)
-                        ):
-                            position_violations += 1
-                        last_write[i] = wr
-                        s.absorb(int(tok), now=now if open_loop else None)
-                    cur_tok[i, 0] = s.tokens[-1]
-                if open_loop:
-                    pump()
+                        stamp = run_clock()  # one stamp for the round's tokens
+                        for i in live:
+                            s = slots[i]
+                            pr = rr.per_row.get(i)
+                            if pr is not None:
+                                s.proposed += pr[0]
+                                s.accepted += pr[1]
+                            for tok in rr.tokens.get(i, ()):
+                                if s.done:  # budget/EOS cut the committed run short
+                                    break
+                                # per committed token the same invariants the
+                                # pre-strategy loop checked per step: the
+                                # physical write index advances by exactly one
+                                # slot, stays inside the logical window, and
+                                # the true position is the write index shifted
+                                # by the row's pad offset
+                                wr = P + s.emitted - 1
+                                pp = s.req.prompt_len + s.emitted - 1
+                                if (
+                                    wr != last_write[i] + 1
+                                    or wr >= P + self.max_new
+                                    or pp != wr - (P - s.req.prompt_len)
+                                ):
+                                    position_violations += 1
+                                last_write[i] = wr
+                                s.absorb(int(tok), stamp)
+                            cur_tok[i, 0] = s.tokens[-1]
+                        if open_loop:
+                            pump()
 
         wall = time.perf_counter() - t0
         # SLO attainment over every *offered* request carrying an SLO:
@@ -751,6 +763,7 @@ class ContinuousScheduler:
             spec_proposed=spec_proposed,
             spec_accepted=spec_accepted,
             modeled_cost=modeled_cost,
+            spans=spans.totals(),
         )
         accounting = SlotAccounting(
             seated=seated_total,

@@ -9,18 +9,37 @@ request distributions (TTFT, end-to-end latency) plus slot utilization.
 ``ServeResult`` bundles the stats with the per-request outcomes — the
 greedy token streams (what the parity tests bit-compare) and one
 :class:`~repro.serve.request.RequestStats` per retired request.
+
+:func:`span` names a stretch of the serving loop's host work.  Each span
+is a ``jax.profiler.TraceAnnotation`` named ``serve:<name>`` (on the
+profiler's host clock, beside the device operations, whenever a profiler
+session is active) and, always, one entry in the run's
+:class:`SpanTotals`, which ``ContinuousScheduler.run`` reports as
+``ServeStats.spans`` (docs/serving.md §Measuring lists the names).
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
+import time
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.serve.request import RequestStats
 
-__all__ = ["ServeStats", "ServeResult", "SlotAccounting", "percentile", "fmt_ms"]
+__all__ = [
+    "ServeStats", "ServeResult", "SlotAccounting", "SpanStat", "SpanTotals",
+    "span", "percentile", "fmt_ms",
+]
+
+SPAN_PREFIX = "serve:"
+# spans during which the loop awaits a device result or sleeps: the rest of
+# a tick is host work with nothing queued on the device (ServeStats.host_s)
+WAIT_SPANS = ("admit.sync", "pool_prefill.sync", "decode.sync", "idle")
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -47,6 +66,80 @@ def fmt_ms(values, q: float) -> str:
     if p is None:
         return "n/a"
     return f"{p * 1e3:.0f}ms"
+
+
+@dataclasses.dataclass(frozen=True)
+class SpanStat:
+    """One span name's totals over a run (seconds on ``perf_counter``)."""
+
+    count: int
+    total_s: float
+    max_s: float  # the longest single span
+    max_at_s: float = 0.0  # when it began, in seconds from the run's start
+
+
+class SpanTotals:
+    """Per-run totals of the host spans: count, summed and longest
+    duration per name, and when the longest began (from the totals'
+    creation, the run's start).  :meth:`active` makes it the target of
+    :func:`span` for the calls inside it (one serve run)."""
+
+    def __init__(self):
+        self._origin = time.perf_counter_ns()
+        self._acc: dict = {}  # name -> [count, total ns, max ns, max start ns]
+
+    def add(self, name: str, start_ns: int, ns: int) -> None:
+        acc = self._acc.get(name)
+        if acc is None:
+            self._acc[name] = [1, ns, ns, start_ns]
+            return
+        acc[0] += 1
+        acc[1] += ns
+        if ns > acc[2]:
+            acc[2], acc[3] = ns, start_ns
+
+    def totals(self) -> dict:
+        """name -> :class:`SpanStat`."""
+        return {name: SpanStat(c, t * 1e-9, m * 1e-9, (at - self._origin) * 1e-9)
+                for name, (c, t, m, at) in self._acc.items()}
+
+    @contextlib.contextmanager
+    def active(self):
+        token = _ACTIVE_TOTALS.set(self)
+        try:
+            yield self
+        finally:
+            _ACTIVE_TOTALS.reset(token)
+
+
+_ACTIVE_TOTALS: contextvars.ContextVar = contextvars.ContextVar(
+    "serve_span_totals", default=None)
+
+
+class span:
+    """Context manager around one stretch of host work: a profiler host
+    span ``serve:<name>`` (a no-op check when no session is active) and
+    its duration added to the active run's :class:`SpanTotals` (none
+    outside a run)."""
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._annotation = jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns = time.perf_counter_ns() - self._t0
+        self._annotation.__exit__(*exc)
+        totals = _ACTIVE_TOTALS.get()
+        if totals is not None:
+            totals.add(self.name, self._t0, ns)
+        return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,6 +210,19 @@ class ServeStats:
     spec_proposed: int = 0  # draft tokens proposed across those rounds
     spec_accepted: int = 0  # of those, accepted by the verify forward
     modeled_cost: float = 0.0  # sum of round costs in exact-step units
+    # ---- host spans (continuous scheduler; empty for old readers)
+    spans: dict = dataclasses.field(default_factory=dict)  # name -> SpanStat
+
+    @property
+    def host_s(self) -> Optional[float]:
+        """Seconds the loop spent in host work with no device result
+        awaited (``tick`` less the sync and idle spans): when the chip has
+        nothing queued.  ``None`` for a run without spans."""
+        tick = self.spans.get("tick")
+        if tick is None:
+            return None
+        waited = sum(self.spans[n].total_s for n in WAIT_SPANS if n in self.spans)
+        return tick.total_s - waited
 
     @property
     def spec_rolled_back(self) -> int:

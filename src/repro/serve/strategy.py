@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve.stats import span
 from repro.train.steps import make_decode_step, make_prefill_step
 
 __all__ = [
@@ -117,6 +118,11 @@ def build_tier_engine(model, capacity: int, *, name, key,
     ``scatter_row(big, small, row)`` is the admission cache-scatter
     primitive (the scheduler owns it; injected to keep this module free
     of cache-layout knowledge).
+
+    Each step's parts carry a ``jax.named_scope`` (``admit/prefill``,
+    ``admit/scatter``, ``admit/argmax``, ``pool_prefill``,
+    ``decode/forward``, ``decode/lm_head``, ``decode/argmax``): HLO
+    metadata that names the device operations in a profile.
     """
     prefill = make_prefill_step(model, capacity)
     decode = make_decode_step(model)
@@ -125,22 +131,30 @@ def build_tier_engine(model, capacity: int, *, name, key,
     # Admission, fused to one dispatch: single-row prefill + scatter
     # into the freed slot + greedy first token.
     def admit_step(params, caches, toks, pos, row):
-        row_caches, logits = prefill(params, {"tokens": toks, "positions": pos})
-        caches = scatter_row(caches, row_caches, row)
-        tok0 = jnp.argmax(logits[0, -1], -1).astype(jnp.int32)
+        with jax.named_scope("admit"):
+            with jax.named_scope("prefill"):
+                row_caches, logits = prefill(
+                    params, {"tokens": toks, "positions": pos})
+            with jax.named_scope("scatter"):
+                caches = scatter_row(caches, row_caches, row)
+            with jax.named_scope("argmax"):
+                tok0 = jnp.argmax(logits[0, -1], -1).astype(jnp.int32)
         return caches, tok0
 
     # Initial fill, when the queue covers every slot: one batched
     # prefill *is* the pool cache — no scatter at all.
     def prefill_pool(params, toks, pos):
-        caches, logits = prefill(params, {"tokens": toks, "positions": pos})
-        return caches, jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+        with jax.named_scope("pool_prefill"):
+            caches, logits = prefill(params, {"tokens": toks, "positions": pos})
+            return caches, jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
 
     # Decode with the greedy argmax fused in (one dispatch per step,
     # and only (B,) token ids cross back to the host).
     def decode_greedy(params, caches, tok, pos, write):
-        logits, caches = decode(params, caches, tok, pos, write)
-        return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), caches
+        with jax.named_scope("decode"):
+            logits, caches = decode(params, caches, tok, pos, write)
+            with jax.named_scope("argmax"):
+                return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), caches
 
     from repro.engine.config import tier_cycle_factor
 
@@ -235,18 +249,19 @@ class GreedyDecode(DecodeStrategy):
                      *, speculate: bool = True) -> RoundResult:
         B = cur_tok.shape[0]
         P = pool.prompt_len
-        # per-row true position + physical write slot; dead lanes park at
-        # the last physical slot with offset 0
-        pos = np.full((B,), pool.capacity - 1, np.int32)
-        write = np.full((B,), pool.capacity - 1, np.int32)
-        for r in rows:
-            pos[r.index] = r.prompt_len + r.emitted - 1
-            write[r.index] = P + r.emitted - 1
-        nxt, caches = engine.decode(
-            pool.params, caches, jnp.asarray(cur_tok),
-            jnp.asarray(pos), jnp.asarray(write),
-        )
-        nxt = np.asarray(nxt)
+        with span("decode.prep"):
+            # per-row true position + physical write slot; dead lanes park
+            # at the last physical slot with offset 0
+            pos = np.full((B,), pool.capacity - 1, np.int32)
+            write = np.full((B,), pool.capacity - 1, np.int32)
+            for r in rows:
+                pos[r.index] = r.prompt_len + r.emitted - 1
+                write[r.index] = P + r.emitted - 1
+            args = (jnp.asarray(cur_tok), jnp.asarray(pos), jnp.asarray(write))
+        with span("decode.dispatch"):
+            nxt, caches = engine.decode(pool.params, caches, *args)
+        with span("decode.sync"):
+            nxt = np.asarray(nxt)
         return RoundResult(
             tokens={r.index: [int(nxt[r.index])] for r in rows},
             caches=caches, steps=1, cost=engine.cost_factor,
@@ -352,9 +367,11 @@ class SelfSpeculative(DecodeStrategy):
             wrt = np.where(w0 + j < cap, w0 + j, cap - 1).astype(np.int32)
             # live rows never clip (emitted <= max_new - 1 so w0 + k < cap);
             # the where only re-parks dead lanes at the last slot
-            nxt, caches = draft_eng.decode(
-                pool.params, caches, tok, jnp.asarray(pos), jnp.asarray(wrt))
-            props[:, j] = np.asarray(nxt)
+            with span("decode.dispatch"):
+                nxt, caches = draft_eng.decode(
+                    pool.params, caches, tok, jnp.asarray(pos), jnp.asarray(wrt))
+            with span("decode.sync"):
+                props[:, j] = np.asarray(nxt)
             tok = nxt[:, None]
 
         # ---- verify phase: one (B, k+1) forward on the verify engine,
@@ -369,11 +386,13 @@ class SelfSpeculative(DecodeStrategy):
                 # (positions arange(k+1): causal, >= 1 visible key, no NaN)
                 starts[i] = cap - (k + 1)
                 vpos[i] = np.arange(k + 1, dtype=np.int32)
-        ver, caches = verify_eng.verify(
-            pool.params, caches, jnp.asarray(vtok), jnp.asarray(vpos),
-            jnp.asarray(starts),
-        )
-        ver = np.asarray(ver)
+        with span("decode.dispatch"):
+            ver, caches = verify_eng.verify(
+                pool.params, caches, jnp.asarray(vtok), jnp.asarray(vpos),
+                jnp.asarray(starts),
+            )
+        with span("decode.sync"):
+            ver = np.asarray(ver)
 
         # ---- accept: longest agreeing prefix + the verify bonus token
         tokens: dict = {}

@@ -175,10 +175,12 @@ def make_prefill_step(model: Model, max_seq: int, *, mem_len: int = 0):
             cache_pos = jnp.int32(0)
         if cfg.use_mrope:
             pos = jnp.broadcast_to(pos[None], (3, b, s))
-        hidden, caches, _ = model.forward(
-            params, tokens, pos, ctx, caches=caches, cache_pos=cache_pos
-        )
-        logits = model.lm_head(params, hidden[:, -1:, :])
+        with jax.named_scope("forward"):
+            hidden, caches, _ = model.forward(
+                params, tokens, pos, ctx, caches=caches, cache_pos=cache_pos
+            )
+        with jax.named_scope("lm_head"):
+            logits = model.lm_head(params, hidden[:, -1:, :])
         return caches, logits
 
     return prefill
@@ -209,10 +211,12 @@ def make_decode_step(model: Model):
             cache_pos = pos if write_pos is None else jnp.asarray(write_pos, jnp.int32)
         if cfg.use_mrope:
             p = jnp.broadcast_to(p[None], (3, b, 1))
-        hidden, new_caches, _ = model.forward(
-            params, token, p, ctx, caches=caches, cache_pos=cache_pos
-        )
-        logits = model.lm_head(params, hidden)
+        with jax.named_scope("forward"):
+            hidden, new_caches, _ = model.forward(
+                params, token, p, ctx, caches=caches, cache_pos=cache_pos
+            )
+        with jax.named_scope("lm_head"):
+            logits = model.lm_head(params, hidden)
         return logits, new_caches
 
     return decode
