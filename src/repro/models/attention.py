@@ -1,22 +1,33 @@
 """Multi-head attention: GQA/MQA, RoPE/M-RoPE, qk-norm, logit softcaps,
 sliding-window (local) masking, and a KV cache for prefill + decode.
 
-Tensor-parallel layout: attention runs on a *flat* head axis H = KV * G
-(k/v are repeated from KV to H at use — the cache stays unrepeated), so a
-single ``model``-axis constraint shards the whole computation whenever H
-divides the axis (true for 8/10 assigned archs at model=16; qwen2-vl H=28
-and recurrentgemma H=10 replicate and are flagged in EXPERIMENTS.md).
+Tensor-parallel layout: prefill and training attend on a *flat* head axis
+H = KV * G (k/v are repeated from KV to H at use — the cache stays
+unrepeated), so a single ``model``-axis constraint shards the whole
+computation whenever H divides the axis (true for 8/10 assigned archs at
+model=16; qwen2-vl H=28 and recurrentgemma H=10 replicate and are flagged
+in EXPERIMENTS.md).
 
 Prefill / training uses a blockwise online-softmax (flash-style)
 formulation: an outer ``lax.map`` over query chunks and an inner
 ``lax.scan`` over key chunks carrying (running max, denominator,
 accumulator) — peak live logits are (B, H, q_chunk, k_chunk) instead of
-(B, H, S, T).
+(B, H, S, T).  A forward of S > 1 tokens over a cache (admission
+prefill, speculative verify) writes its k/v into the cache first and
+attends over the written cache.
 
-Decode (s == 1) takes the direct path with the KV cache *sequence* axis
-sharded over the model axis (flash-decode style): per-device partial
-logits over T/|model| keys, with the softmax max/sum reductions lowering
-to all-reduces — this is what makes decode_32k × batch 128 fit.
+Decode (s == 1 over a cache) reads the cache in place and does not write
+it: the query, grouped as (B, KV, G, hd), is contracted against the
+unrepeated cache in its own dtype with float32 accumulation, the write
+slot masked out; the new token's own key and value join the softmax
+beside the T cache scores, and the output is ``p_cache @ V_cache +
+p_new * v_new``.  ``attention`` then returns the new token's k/v as the
+cache, and the caller writes the (layers, B) new entries into the pool
+with one scatter (:func:`write_kv`) after its layer scan — the pool is
+never copied or rewritten whole.  The cache *sequence* axis is sharded
+over the model axis (flash-decode style): per-device partial logits over
+T/|model| keys, with the softmax max/sum reductions lowering to
+all-reduces — this is what makes decode_32k × batch 128 fit.
 """
 
 from __future__ import annotations
@@ -27,19 +38,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import DP, TP, ambient_mesh, constrain
+from repro.distributed.sharding import DP, TP, constrain
 from repro.models import layers
 from repro.models.layers import Ctx
 
-__all__ = ["KVCache", "init_attn", "attention", "init_kv_cache"]
+__all__ = ["KVCache", "init_attn", "attention", "init_kv_cache", "write_kv"]
 
 NEG_INF = -2.3819763e38  # bf16-safe large negative
 Q_CHUNK = 1024
 K_CHUNK = 1024
-
-
-def _no_mesh() -> bool:
-    return ambient_mesh() is None
 
 
 class KVCache(NamedTuple):
@@ -59,6 +66,37 @@ def _row_update(cache: jax.Array, update: jax.Array, starts: jax.Array) -> jax.A
         return jax.lax.dynamic_update_slice(c, u, (p, 0, 0))
 
     return jax.vmap(one)(cache, update, starts)
+
+
+def write_kv(cache: KVCache, new: KVCache, cache_pos) -> KVCache:
+    """Write a decode step's new entries into a cache, in place.
+
+    ``cache`` leaves are (..., B, T, KV, hd), ``new`` leaves (..., B, 1, KV,
+    hd) with the same leading (stacked-layer) axes.  A scalar ``cache_pos``
+    is one ``dynamic_update_slice``; a per-row ``(B,)`` vector is one
+    scatter of (KV, hd) windows at (leading..., row, cache_pos[row]),
+    clamped into range as ``dynamic_update_slice`` clamps.  Indexing every
+    axis but the last two keeps each window contiguous in the cache's own
+    layout, so the scatter writes in place.
+    """
+    pos = jnp.asarray(cache_pos, jnp.int32)
+
+    def put(c, u):
+        lead = c.ndim - 4
+        u = u.astype(c.dtype)
+        if pos.ndim == 0:
+            return jax.lax.dynamic_update_slice(c, u, (0,) * (lead + 1) + (pos, 0, 0))
+        n = lead + 1  # the leading axes and the row axis
+        idx = tuple(
+            jnp.arange(c.shape[i], dtype=jnp.int32).reshape(
+                [-1 if j == i else 1 for j in range(n)])
+            for i in range(n)
+        ) + (pos.reshape([1] * lead + [-1]),)
+        return c.at[idx].set(u[..., 0, :, :], mode="clip",
+                             unique_indices=True, indices_are_sorted=True)
+
+    with jax.named_scope("attn"), jax.named_scope("cache_update"):
+        return KVCache(put(cache.k, new.k), put(cache.v, new.v))
 
 
 def init_attn(key, cfg: ModelConfig, dtype, cross: bool = False) -> dict:
@@ -97,14 +135,16 @@ def _allow(q_pos, k_pos, *, causal: bool, window: Optional[int]):
     return m
 
 
+def _scale_cap(s, softcap, scale):
+    s = s * scale
+    return jnp.tanh(s / softcap) * softcap if softcap else s
+
+
 def _scores(q, k, softcap, scale):
     # q: (B, Sq, H, hd), k: (B, Sk, H, hd) -> (B, H, Sq, Sk)
     with jax.named_scope("scores"):
         s = jnp.einsum("bqhd,bthd->bhqt", q.astype(jnp.float32), k.astype(jnp.float32))
-        s *= scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        return s
+        return _scale_cap(s, softcap, scale)
 
 
 def _attend_direct(q, k, v, q_pos, k_pos, *, causal, window, softcap, scale):
@@ -113,6 +153,52 @@ def _attend_direct(q, k, v, q_pos, k_pos, *, causal, window, softcap, scale):
     logits = jnp.where(allow[:, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqt,bthd->bqhd", probs, v.astype(jnp.float32))
+
+
+def _slot_positions(t: int, last: jax.Array, offset: jax.Array) -> jax.Array:
+    """(B, T) key position of each cache slot: slot j of row i holds
+    position ``j - offset[i]``; slots outside ``[offset[i], last[i]]``
+    (left pads, the unwritten tail, an admission hole) are -1."""
+    jj = jnp.arange(t, dtype=jnp.int32)[None, :]
+    ok = (jj >= offset[:, None]) & (jj <= last[:, None])
+    return jnp.where(ok, jj - offset[:, None], -1)
+
+
+def _attend_decode(q, cache, k_new, v_new, q_pos, k_pos, k_pos_new, *,
+                   window, softcap, scale):
+    """One query per row over the cache as stored, plus its own key.
+
+    q (B, 1, H, hd); ``cache`` leaves (B, T, KV, hd) with the write slot
+    already masked out of ``k_pos`` (B, T); k_new/v_new (B, 1, KV, hd) in
+    the cache dtype; k_pos_new (B,).  The query is grouped to (B, KV, G,
+    hd) and contracted against the unrepeated cache with float32
+    accumulation — equal to a float32 product of the two operands, since
+    a product of two bf16 values is exact in float32.  Probabilities stay
+    float32 into the product with V (``Precision.HIGHEST``).
+    """
+    b, _, h, hd = q.shape
+    kvh = cache.k.shape[2]
+    f32 = jnp.float32
+    qg = q.reshape(b, kvh, h // kvh, hd)
+    with jax.named_scope("scores"):
+        s_cache = _scale_cap(jnp.einsum("bkgd,btkd->bkgt", qg, cache.k,
+                                        preferred_element_type=f32), softcap, scale)
+        s_new = _scale_cap(jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0],
+                                      preferred_element_type=f32), softcap, scale)
+    allow = _allow(q_pos, k_pos, causal=True, window=window)  # (B, 1, T)
+    allow_new = _allow(q_pos, k_pos_new[:, None], causal=True, window=window)
+    s_cache = jnp.where(allow[:, None], s_cache, NEG_INF)
+    s_new = jnp.where(allow_new[:, None], s_new[..., None], NEG_INF)
+    # softmax over the T cache scores and the new token's score together
+    m = jnp.maximum(s_cache.max(axis=-1, keepdims=True), s_new)
+    e_cache, e_new = jnp.exp(s_cache - m), jnp.exp(s_new - m)
+    denom = e_cache.sum(axis=-1, keepdims=True) + e_new
+    p_cache, p_new = e_cache / denom, e_new / denom
+    out = jnp.einsum("bkgt,btkd->bkgd", p_cache, cache.v,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=f32)
+    out = out + p_new * v_new[:, 0, :, None, :].astype(f32)
+    return out.reshape(b, 1, h, hd)
 
 
 def _attend_flash(q, k, v, q_pos, k_pos, *, causal, window, softcap, scale,
@@ -188,6 +274,11 @@ def attention(
     This is what lets left-padded prompts decode at their true positions
     and lets the continuous-batching scheduler keep rows at different
     depths of one physical cache.
+
+    Returns ``(out, cache)``.  With S > 1 the cache comes back written.
+    A decode step (S == 1) leaves ``cache`` untouched and returns only the
+    new token's entries, (B, 1, KV, hd) in the cache dtype: the caller
+    writes them at ``cache_pos`` with :func:`write_kv`.
     """
     cfg = ctx.cfg
     b, s, _ = x.shape
@@ -208,9 +299,37 @@ def attention(
         k = _apply_rope(k, positions if kv_positions is None else kv_positions, ctx)
     q = constrain(q, DP, None, TP, None)
 
-    decode = s == 1 and cache is not None
-    per_row = cache_pos is not None and getattr(cache_pos, "ndim", 0) >= 1
-    if cache is not None and kv_x is None:
+    causal_ = causal and kv_x is None
+    window = cfg.local_window if local else None
+    scale = hd**-0.5
+    softcap = cfg.attn_logit_softcap
+    q_pos = mpos
+    if cache is None or kv_x is not None:
+        new_cache = None
+        k_pos = mpos if kv_positions is None else kv_positions
+    else:
+        # physical slot of the newest token, and each row's left-pad
+        # offset (physical - true position)
+        per_row = getattr(cache_pos, "ndim", 0) >= 1
+        last = jnp.asarray(cache_pos, jnp.int32) + jnp.int32(s - 1)
+        if per_row:
+            offset = last - mpos[:, -1]
+        else:
+            last = jnp.broadcast_to(last, (b,))
+            offset = jnp.zeros((b,), jnp.int32)
+        if s == 1:
+            # decode: read the cache in place, write slot masked out; the
+            # caller writes the new entries (write_kv)
+            new_cache = KVCache(k.astype(cache.k.dtype), v.astype(cache.v.dtype))
+            old = KVCache(constrain(cache.k, DP, TP, None, None),
+                          constrain(cache.v, DP, TP, None, None))
+            out = _attend_decode(
+                q, old, new_cache.k, new_cache.v, q_pos,
+                _slot_positions(cache.k.shape[1], last - 1, offset), last - offset,
+                window=window, softcap=softcap, scale=scale,
+            )
+            out = constrain(out.reshape(b, s, h * hd).astype(x.dtype), DP, None, TP)
+            return layers.dense(out, params["wo"], ctx, "attn"), new_cache
         with jax.named_scope("cache_update"):
             if per_row:
                 starts = jnp.asarray(cache_pos, jnp.int32)
@@ -223,33 +342,9 @@ def attention(
                 vfull = jax.lax.dynamic_update_slice(
                     cache.v, v.astype(cache.v.dtype), (0, cache_pos, 0, 0)
                 )
-        if decode:  # flash-decode: shard the cache sequence axis over TP
-            kfull = constrain(kfull, DP, TP, None, None)
-            vfull = constrain(vfull, DP, TP, None, None)
         new_cache = KVCache(kfull, vfull)
         k, v = kfull, vfull
-        t = kfull.shape[1]
-        jj = jnp.arange(t, dtype=jnp.int32)[None, :] * jnp.ones((b, 1), jnp.int32)
-        if per_row:
-            last = starts + jnp.int32(s - 1)  # (B,) physical slot of newest token
-            offset = last - mpos[:, -1]  # physical - true == per-row left-pad
-            k_pos = jnp.where(
-                (jj >= offset[:, None]) & (jj <= last[:, None]),
-                jj - offset[:, None],
-                -1,
-            )
-        else:
-            k_pos = jnp.where(jj <= cache_pos + s - 1, jj, -1)
-        q_pos = mpos
-    else:
-        new_cache = None
-        k_pos = mpos if kv_positions is None else kv_positions
-        q_pos = mpos
-
-    causal_ = causal and kv_x is None
-    window = cfg.local_window if local else None
-    scale = hd**-0.5
-    softcap = cfg.attn_logit_softcap
+        k_pos = _slot_positions(kfull.shape[1], last, offset)
 
     ap_attn = cfg.approx.for_target("attn") if (
         cfg.approx.enabled and "attn" in cfg.approx.targets
@@ -259,10 +354,9 @@ def attention(
         and ap_attn.mode in ("bitexact", "lowrank")
         and ap_attn.backend != "reference"
         and cfg.attn_impl == "pallas"
-        and not decode
     )
 
-    if not decode and cfg.attn_impl == "pallas":
+    if cfg.attn_impl == "pallas":
         # VMEM-resident flash kernel; k/v stay unrepeated (GQA head
         # mapping happens in the BlockSpec index_map, not in HBM)
         from repro.kernels.flash_attention import flash_attention
@@ -299,28 +393,15 @@ def attention(
                 q, k, v, q_pos, k_pos, causal_, window, softcap, scale,
                 _block(q.shape[1]), _block(k.shape[1]), use_interpret(),
             )
-    elif decode and cfg.attn_impl == "pallas" and _no_mesh():
-        # single-device serving: stream the KV cache through VMEM
-        # (multi-device decode keeps the XLA path — the cache is
-        # sequence-sharded over the model axis there)
-        from repro.kernels.flash_attention import flash_decode
-        from repro.kernels.ops import use_interpret
-
-        out = flash_decode(
-            q[:, 0], k, v, mpos[:, -1], k_pos,
-            window=window, softcap=softcap, scale=scale,
-            interpret=use_interpret(),
-        )[:, None]
     else:
         # GQA: repeat kv to the flat head axis (cache stays unrepeated)
         if g > 1:
             with jax.named_scope("gqa_repeat"):
                 k = jnp.repeat(k, g, axis=2)
                 v = jnp.repeat(v, g, axis=2)
-        if not decode:
-            k = constrain(k, DP, None, TP, None)
-            v = constrain(v, DP, None, TP, None)
-        if not decode and (s > Q_CHUNK or k.shape[1] > 4 * K_CHUNK):
+        k = constrain(k, DP, None, TP, None)
+        v = constrain(v, DP, None, TP, None)
+        if s > Q_CHUNK or k.shape[1] > 4 * K_CHUNK:
             out = _attend_flash(
                 q, k, v, q_pos, k_pos, causal=causal_, window=window, softcap=softcap, scale=scale
             )

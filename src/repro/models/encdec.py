@@ -237,6 +237,8 @@ def decode_forward(
             _remat(body, cfg), x,
             (params["dec_scan"], caches.self_kv, caches.cross_k, caches.cross_v),
         )
+        if s == 1:  # decode: the scan handed back only the new entries
+            new_skv = attention.write_kv(caches.self_kv, new_skv, cache_pos)
         new_caches = DecCache(new_skv, caches.cross_k, caches.cross_v)
     else:
         x, _ = jax.lax.scan(_remat(body, cfg), x, params["dec_scan"])

@@ -8,7 +8,11 @@ O(depth)), with any remainder layers unrolled.  Remat (configurable
 policy) wraps the group body.
 
 Caches (KV / RG-LRU / SSD states) are pytrees stacked the same way and
-threaded through the scan as (xs -> ys).
+threaded through the scan as (xs -> ys).  A decode step (one token over
+caches) is the exception for KV caches: the scan reads the stacked pool
+as ``xs`` without rewriting it, emits each layer's new (B, 1, KV, hd) k/v
+as ``ys``, and one ``attention.write_kv`` after the scan writes all of
+them into the (donated) pool.
 
 The forward pass returns final *hidden states*; logits are produced by
 ``lm_head()`` (or, in training, never fully materialized — the loss is
@@ -125,6 +129,15 @@ def _init_cache_for(cfg: ModelConfig, kind: str, batch: int, max_seq: int, dtype
     raise ValueError(kind)
 
 
+def _commit(kind: str, cache: Any, out: Any, cache_pos, decode: bool):
+    """A layer's cache after the forward.  In a decode step an attention
+    layer hands back only the new token's k/v, written here; every other
+    layer and step hands back its whole new cache."""
+    if decode and kind in ("attn_global", "attn_local"):
+        return attention.write_kv(cache, out, cache_pos)
+    return out
+
+
 # --------------------------------------------------------------------- init
 def init_params(key, cfg: ModelConfig) -> dict:
     dtype = jnp.dtype(cfg.dtype)
@@ -219,22 +232,21 @@ def forward(
     period = len(cfg.layer_pattern)
     repeats = cfg.num_layers // period if cfg.scan_layers else 0
     new_caches: dict = {}
+    decode = caches is not None and x.shape[1] == 1
 
     if repeats:
         def group_body(carry, xs):
             x, aux = carry
             gparams, gcache = xs
+            out = {}
             for i in range(period):
                 kind = cfg.layer_pattern[i]
                 sub_cache = gcache[f"sub{i}"] if gcache is not None else None
-                x, nc, a = _apply_block(
+                x, out[f"sub{i}"], a = _apply_block(
                     gparams[f"sub{i}"], kind, x, positions, ctx, sub_cache, cache_pos
                 )
-                if gcache is not None:
-                    gcache = dict(gcache)
-                    gcache[f"sub{i}"] = nc
                 aux = aux + a
-            return (x, aux), gcache
+            return (x, aux), (out if gcache is not None else None)
 
         body = _remat(group_body, cfg)
         scan_caches = caches.get("scan") if caches else None
@@ -246,10 +258,14 @@ def forward(
                 params["scan"],
             )
         else:
-            (x, aux), new_scan = jax.lax.scan(
+            (x, aux), outs = jax.lax.scan(
                 body, (x, jnp.float32(0.0)), (params["scan"], scan_caches)
             )
-            new_caches["scan"] = new_scan
+            new_caches["scan"] = {
+                f"sub{i}": _commit(kind, scan_caches[f"sub{i}"], outs[f"sub{i}"],
+                                   cache_pos, decode)
+                for i, kind in enumerate(cfg.layer_pattern)
+            }
     else:
         aux = jnp.float32(0.0)
 
@@ -262,7 +278,8 @@ def forward(
         )
         aux = aux + a
         if rcache is not None:
-            new_caches.setdefault("rem", [None] * len(rem_kinds))[i] = nc
+            new_caches.setdefault("rem", [None] * len(rem_kinds))[i] = _commit(
+                kind, rcache, nc, cache_pos, decode)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, (new_caches if caches else None), aux

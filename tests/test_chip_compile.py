@@ -87,3 +87,56 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, m, shape):
     assert mem.output_size_in_bytes == out_bytes
     # padding and operand re-layout stay small beside the operands
     assert mem.temp_size_in_bytes <= 4 * mem.argument_size_in_bytes
+
+
+# The benchmark's configurations at their cells' pool shapes: (registry
+# name, overrides, rows); every row holds a 128-token prompt bucket and
+# 256 outputs.
+DECODE_CELLS = {
+    "qwen3-0.6b": ("qwen3-0.6b", {}, 32),
+    "yi-9b-24l": ("yi-9b", {"num_layers": 24}, 16),
+}
+POOL_SLOTS = 384
+
+
+@pytest.mark.parametrize("cell", list(DECODE_CELLS))
+def test_decode_step_reads_pool_in_place(one_chip, cell):
+    """The exact tier's decode step, compiled for the chip with the pool
+    donated, neither copies the stacked pool nor repeats it to the query
+    heads, has no loop but the layer scan, and needs temporaries of
+    under a tenth of the pool."""
+    import re
+
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+    from repro.serve.scheduler import _apply_pool_quality, _scatter_row
+    from repro.serve.strategy import build_tier_engine
+
+    arch, over, rows = DECODE_CELLS[cell]
+    model, tier = _apply_pool_quality(build_model(get_config(arch, **over)), "exact")
+    cfg = model.cfg
+    engine = build_tier_engine(model, POOL_SLOTS, name=tier, key=tier,
+                               scatter_row=_scatter_row)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = abstract(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    pool = abstract(jax.eval_shape(
+        lambda: model.init_caches(rows, POOL_SLOTS, jnp.dtype(cfg.dtype))))
+    tok = jax.ShapeDtypeStruct((rows, 1), jnp.int32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    compiled = engine.decode.lower(params, pool, tok, vec, vec).compile()
+    hlo = compiled.as_text()
+
+    kv, hd = cfg.num_kv_heads, cfg.head_dim
+    stacked = f"[{cfg.num_layers},{rows},{POOL_SLOTS},{kv},{hd}]"
+    copies = re.findall(rf"= \w+{re.escape(stacked)}\S* copy\(", hlo)
+    assert not copies, f"the stacked pool is copied: {copies}"
+    repeated = f"[{rows},{POOL_SLOTS},{kv},{cfg.num_heads // kv},{hd}]"
+    repeats = re.findall(rf"= \w+{re.escape(repeated)}\S* broadcast\(", hlo)
+    assert not repeats, f"the cache is repeated to the query heads: {repeats}"
+    assert len(re.findall(r" while\(", hlo)) == 1, "a loop besides the layer scan"
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10

@@ -202,9 +202,10 @@ def test_spans_nest_on_the_profilers_host_clock(sched, tmp_path):
     assert inside("admit.sync", "admit") == []
 
 
-# the names each jitted step's HLO metadata must carry
+# the names each jitted step's HLO metadata must carry; decode reads the
+# unrepeated cache, so only admission repeats k/v to the query heads
 DECODE_SCOPES = ("decode/forward", "decode/lm_head", "decode/argmax",
-                 "attn/cache_update", "attn/gqa_repeat", "attn/scores", "mlp/")
+                 "attn/cache_update", "attn/scores", "mlp/")
 ADMIT_SCOPES = ("admit/prefill", "admit/scatter", "admit/argmax",
                 "attn/cache_update", "attn/gqa_repeat", "attn/scores", "mlp/")
 
@@ -222,6 +223,7 @@ def test_compiled_steps_carry_named_scopes(sched):
         sched.params, caches, jnp.zeros((B, 1), jnp.int32), z, z).compile())
     for scope in DECODE_SCOPES:
         assert scope in decode, scope
+    assert "attn/gqa_repeat" not in decode
     toks = jnp.zeros((1, PROMPT), jnp.int32)
     admit = _op_names(eng.admit_step.lower(
         sched.params, caches, toks, toks, jnp.int32(0)).compile())
