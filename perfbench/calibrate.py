@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT))
     import jax
 
-    from perfbench import harness, run, traffic, weights
+    from perfbench import harness, run, traffic
 
     if jax.devices()[0].platform != "tpu":
         print("calibrate: no TPU", file=sys.stderr)
@@ -52,7 +52,7 @@ def main(argv=None) -> int:
     model, rows = c.sched.model, []
     for i, seed in enumerate(seeds):
         if i:
-            c.sched.params = weights.program_params(c.cfg, model, seed)
+            c.sched.params = c.family.program_params(c.cfg, model, seed)
         offer = traffic.build(c.mix, c.cfg["vocab_size"], seed, args.seconds)
         reqs = harness.requests(c, offer)
         if i == 0:

@@ -3,8 +3,11 @@
 The system under test is ``repro.serve.scheduler.ContinuousScheduler.run``
 on the wall clock, one slot pool at the cell's tier, over a model built by
 ``repro.models.registry.build_model`` from the cell's configuration.  The
-benchmark makes the weights (``perfbench.weights``), the traffic
-(``perfbench.traffic``) and the reference (``perfbench.reference``) itself.
+benchmark makes the traffic (``perfbench.traffic``) itself, and takes the
+program's checked config, the weights, the reference and the operation
+count from the configuration's family (``perfbench/families/<family>.py``;
+``dense``, over ``perfbench.weights`` and ``perfbench.reference``, where
+the file names none).
 """
 
 from __future__ import annotations
@@ -21,28 +24,12 @@ from typing import Optional
 
 import numpy as np
 
-from perfbench import reference, spec as spec_mod, traffic, weights
+from perfbench import spec as spec_mod, traffic
 
 # A traced run traces this much of its window, from 40% of the way in: a
 # device trace of a whole minute of serving loses events, and reading ten
 # seconds of a 30 ms decode step took over five minutes.
 TRACE_SECONDS = 2.0
-# The published keys a configuration file states, and the field of the
-# program's ModelConfig each must equal.
-_SIZES = {
-    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings", "qk_norm": "use_qk_norm",
-    "torch_dtype": "dtype",
-}
-# What the reference implements; a program config with anything else is
-# not the model the file describes.
-_PLAIN = {"layer_pattern": ("attn_global",), "ffn_activation": "silu",
-          "use_post_norm": False, "embed_scale": False, "num_experts": 0,
-          "final_logit_softcap": None, "attn_logit_softcap": None,
-          "use_mrope": False, "encoder_layers": 0, "scan_layers": True}
 
 
 @dataclasses.dataclass
@@ -59,23 +46,7 @@ class RunRecord:
     device_kind: str
     chips: int
     trace: Optional[dict] = None  # perfbench.trace.summarize, --trace 1 only
-
-
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file, checked against
-    every size the file states."""
-    from repro.configs.registry import get_config
-
-    prog = get_config(cfg["program"]["registry"], **cfg["program"].get("overrides", {}))
-    for key, field in _SIZES.items():
-        if getattr(prog, field) != cfg[key]:
-            raise ValueError(f"{cfg['name']}: program {field}={getattr(prog, field)!r} "
-                             f"but the configuration states {key}={cfg[key]!r}")
-    for field, want in _PLAIN.items():
-        if getattr(prog, field) != want:
-            raise ValueError(f"{cfg['name']}: program {field}={getattr(prog, field)!r}, "
-                             f"the reference implements {want!r}")
-    return prog
+    family: object = None  # the configuration's family module
 
 
 class _CompileCounter:
@@ -173,6 +144,7 @@ class Cell:
     workload: str
     entry: dict
     cfg: dict
+    family: object
     mix: dict
     cell: dict
     sched: object
@@ -193,16 +165,16 @@ def prepare(root: Path, workload: str, seed: int, trace: bool = False) -> Cell:
     if entry["chips"] != 1:
         raise NotImplementedError("the harness drives one chip per cell")
     cfg, mix = spec.config(entry["config"]), spec.traffic(entry["traffic"])
-    cell = spec.cell(workload)
-    model = build_model(program_config(cfg))
-    params = weights.program_params(cfg, model, seed)
+    cell, family = spec.cell(workload), spec.family(cfg)
+    model = build_model(family.program_config(cfg))
+    params = family.program_params(cfg, model, seed)
     jax.block_until_ready(params)
     sched = ContinuousScheduler(
         model, params, batch_size=mix["slots"], prompt_len=mix["bucket"],
         max_new=mix["max_new"], quality=cell["tier"],
         strategy=_spanned_greedy() if trace else None)
-    return Cell(spec=spec, workload=workload, entry=entry, cfg=cfg, mix=mix,
-                cell=cell, sched=sched, device=jax.devices()[0])
+    return Cell(spec=spec, workload=workload, entry=entry, cfg=cfg, family=family,
+                mix=mix, cell=cell, sched=sched, device=jax.devices()[0])
 
 
 def requests(c: Cell, offer) -> list:
@@ -265,9 +237,9 @@ def check(c: Cell, seed: int, offer, outputs: dict, *, control: bool = False, lo
               if len(out) != offer.budgets[i] or out.min() < 0 or out.max() >= vocab)
     ids = _sample(outputs, offer.prompts, c.cell["check"]["requests"], seed)
     t_ref = time.perf_counter()
-    got = reference.gaps(c.cfg, seed, [offer.prompts[i] for i in ids],
-                         [outputs[i] for i in ids], c.mix["bucket"] + c.mix["max_new"],
-                         control=control)
+    got = c.family.gaps(c.cfg, seed, [offer.prompts[i] for i in ids],
+                        [outputs[i] for i in ids], c.mix["bucket"] + c.mix["max_new"],
+                        control=control)
     log(f"reference over {len(ids)} requests, {len(got['served'])} served tokens "
         f"in {time.perf_counter() - t_ref:.3f} s: max gap {float(got['served'].max())!r}, "
         f"mean gap {float(got['served'].mean())!r}")
@@ -332,7 +304,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
 
     run = RunRecord(cfg=c.cfg, setup_s=setup_s, window_s=window_s,
                     tokens_out=tokens_out, requests=served, stats=stats,
-                    device_kind=c.device.device_kind, chips=chips, trace=summary)
+                    device_kind=c.device.device_kind, chips=chips, trace=summary,
+                    family=c.family)
     wanted = c.spec.per_layer(workload) if trace else c.spec.end_to_end(workload)
     metrics = {}
     for m in wanted:

@@ -6,9 +6,17 @@ Layout under the benchmark directory (``perfbench/``)::
     traffic/<traffic>.json    parameters of the one traffic generator
     cells/<workload>.json     tier and correctness limits of one cell
     metrics/<metric>.py       one reader per metric: read(run) -> float | None
+    families/<family>.py      what a configuration file's ``family`` names
+                              ("dense" where it names none): four functions,
+                              program_config(cfg) -> the program's checked
+                              ModelConfig; program_params(cfg, model, seed) ->
+                              its weights, made on the device; gaps(cfg, seed,
+                              prompts, served, length, *, control=False) -> the
+                              reference's logit gaps; request_ops(cfg,
+                              prompt_len, tokens_out) -> model operations
 
-Adding a cell, a mix, a configuration or a metric adds files and
-entries; no existing file changes.
+Adding a cell, a mix, a configuration, a family or a metric adds files
+and entries; no existing file changes.
 """
 
 from __future__ import annotations
@@ -18,6 +26,7 @@ import json
 from pathlib import Path
 
 BENCH_DIR = "perfbench"
+FAMILY_API = ("program_config", "program_params", "gaps", "request_ops")
 
 
 class Spec:
@@ -75,11 +84,30 @@ class Spec:
         path = self.dir / "metrics" / f"{metric}.py"
         if not path.is_file():
             raise FileNotFoundError(f"metric {metric!r} has no reader at {path}")
-        mod_spec = importlib.util.spec_from_file_location(
-            f"perfbench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return _module(path, f"perfbench_metric_{metric}").read
+
+    def family(self, cfg: dict):
+        """The module of ``families/<family>.py`` for the configuration
+        ``cfg``, with the functions of ``FAMILY_API``."""
+        name = cfg.get("family", "dense")
+        path = self.dir / "families" / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(f"configuration {cfg['name']!r} names family "
+                                    f"{name!r}, which has no module at {path}")
+        mod = _module(path, f"perfbench_family_{name}")
+        missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+        if missing:
+            raise AttributeError(f"family module {path} lacks {missing}")
+        return mod
+
+
+def _module(path: Path, name: str):
+    """The Python file at ``path``, loaded as a module of its own."""
+    mod_spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def _read_json(path: Path) -> dict:

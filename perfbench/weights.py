@@ -20,9 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# A leaf is named here, or given by the integer id a family's own table
+# assigns it, from ``FAMILY_LEAF_BASE`` up, so that no two leaves share
+# their bytes.
 _LEAF = {"embed": 0, "head": 1, "final_norm": 2, "ln1": 3, "ln2": 4, "q": 5,
          "k": 6, "v": 7, "o": 8, "q_norm": 9, "k_norm": 10, "gate": 11,
          "up": 12, "down": 13}
+FAMILY_LEAF_BASE = 100
 _BYTE_STD = math.sqrt((256 ** 2 - 1) / 12.0)  # std of u - 127.5
 _NORM_EXP = 9  # norm deltas within +-0.25
 
@@ -38,16 +42,24 @@ def _exp(fan_in: int) -> int:
     return int(round(math.log2(_BYTE_STD * math.sqrt(fan_in))))
 
 
-def _bytes(key, leaf: str, layer: int, shape) -> jax.Array:
-    k = jax.random.fold_in(jax.random.fold_in(key, _LEAF[leaf]), layer)
+def _leaf_id(leaf: str | int) -> int:
+    if isinstance(leaf, str):
+        return _LEAF[leaf]
+    if leaf < FAMILY_LEAF_BASE:
+        raise ValueError(f"a family's leaf ids start at {FAMILY_LEAF_BASE}, got {leaf}")
+    return leaf
+
+
+def _bytes(key, leaf: str | int, layer: int, shape) -> jax.Array:
+    k = jax.random.fold_in(jax.random.fold_in(key, _leaf_id(leaf)), layer)
     return jax.random.bits(k, shape, jnp.uint8).astype(jnp.float32) - 127.5
 
 
-def matrix(key, leaf: str, layer: int, shape, fan_in: int) -> jax.Array:
+def matrix(key, leaf: str | int, layer: int, shape, fan_in: int) -> jax.Array:
     return (_bytes(key, leaf, layer, shape) * 2.0 ** -_exp(fan_in)).astype(jnp.bfloat16)
 
 
-def norm_delta(key, leaf: str, layer: int, shape) -> jax.Array:
+def norm_delta(key, leaf: str | int, layer: int, shape) -> jax.Array:
     return (_bytes(key, leaf, layer, shape) * 2.0 ** -_NORM_EXP).astype(jnp.bfloat16)
 
 
@@ -104,6 +116,15 @@ def _path_names(path) -> tuple:
     return tuple(getattr(p, "key", getattr(p, "name", p)) for p in path)
 
 
+def _checked(names: tuple, out: jax.Array, sds) -> jax.Array:
+    """``out`` as the program's leaf ``names``, if it has the shape and
+    dtype that the program's ``init_params`` gives that leaf (``sds``)."""
+    if out.shape != sds.shape or out.dtype != sds.dtype:
+        raise ValueError(f"parameter {names}: benchmark makes {out.shape} {out.dtype}, "
+                         f"program wants {sds.shape} {sds.dtype}")
+    return out
+
+
 def program_params(cfg: dict, model, seed: int):
     """The program's parameter tree for ``model``, made on the device in one
     jitted call from ``seed``; every leaf is checked against the shape and
@@ -125,11 +146,7 @@ def program_params(cfg: dict, model, seed: int):
                 out = stacked[_PROGRAM_LAYER[names[2:]]]
             else:
                 raise ValueError(f"unexpected program parameter {names}")
-            if out.shape != sds.shape or out.dtype != sds.dtype:
-                raise ValueError(f"parameter {names}: benchmark makes "
-                                 f"{out.shape} {out.dtype}, program wants "
-                                 f"{sds.shape} {sds.dtype}")
-            return out
+            return _checked(names, out, sds)
 
         return jax.tree_util.tree_map_with_path(leaf, shapes)
 
