@@ -89,7 +89,9 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     # block pattern, cycled over layers: entries in
-    # {"attn_global", "attn_local", "rglru", "ssd"}
+    # {"attn_global", "attn_local", "rglru", "ssd", "attn_ssd"}; "attn_ssd"
+    # runs global attention and an SSD mixer side by side on one normed
+    # input and sums them (Falcon-H1)
     layer_pattern: tuple = ("attn_global",)
     ffn_activation: str = "silu"  # silu -> SwiGLU, gelu -> GeGLU
     use_qk_norm: bool = False
@@ -113,6 +115,8 @@ class ModelConfig:
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
+    ssm_groups: int = 1  # B/C groups; heads map to groups in contiguous blocks
+    ssm_norm: bool = False  # norm(y * silu(z)) over each group before out_proj
     d_inner: int = 0
     # encoder-decoder (audio family)
     encoder_layers: int = 0
@@ -120,6 +124,16 @@ class ModelConfig:
     frontend: Optional[str] = None  # "patches" | "frames"
     tie_embeddings: bool = True
     norm_eps: float = 1e-6
+    # muP multipliers (Falcon-H1); a multiplier of 1.0 is not applied
+    embed_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)  # in_proj's z, x, B, C, dt
+    mlp_multipliers: tuple = (1.0, 1.0)  # gate projection, down projection
     dtype: str = "bfloat16"
     # substrate knobs
     remat: str = "full"  # none | dots | full

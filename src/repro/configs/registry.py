@@ -28,6 +28,7 @@ ARCHS = {
     "kimi-k2-1t-a32b": "kimi_k2_1t",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "mamba2-130m": "mamba2_130m",
+    "falcon-h1-34b": "falcon_h1_34b",
     "seamless-m4t-large-v2": "seamless_m4t_large",
     "paper-multiplier": "paper_multiplier",
 }

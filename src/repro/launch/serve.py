@@ -168,7 +168,8 @@ def main(argv=None) -> None:
         scheduler = "continuous" if supports_continuous(cfg) else "static"
         if scheduler == "static":
             print(f"# {cfg.name}: auto-selected --scheduler static "
-                  f"(continuous supports attention-only decoder stacks)")
+                  f"(continuous supports attention and SSD decoder stacks; "
+                  f"RG-LRU layers absorb left pads, encoder-decoders have no slot cache)")
     if args.data_parallel and scheduler != "continuous":
         ap.error("--data-parallel only applies to --scheduler continuous")
     if args.loop == "open" and scheduler != "continuous":

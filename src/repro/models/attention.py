@@ -1,5 +1,6 @@
 """Multi-head attention: GQA/MQA, RoPE/M-RoPE, qk-norm, logit softcaps,
-sliding-window (local) masking, and a KV cache for prefill + decode.
+sliding-window (local) masking, muP multipliers on the input, the keys and
+the output (applied where not 1), and a KV cache for prefill + decode.
 
 Tensor-parallel layout: prefill and training attend on a *flat* head axis
 H = KV * G (k/v are repeated from KV to H at use — the cache stays
@@ -286,9 +287,11 @@ def attention(
     g = h // kvh
     mpos = positions if not cfg.use_mrope else positions[0]  # masks use t-ids
 
+    x = layers.scale(x, cfg.attn_in_multiplier)
     q = layers.dense(x, params["wq"], ctx, "attn").reshape(b, s, h, hd)
     src = x if kv_x is None else kv_x
     k = layers.dense(src, params["wk"], ctx, "attn").reshape(b, src.shape[1], kvh, hd)
+    k = layers.scale(k, cfg.key_multiplier)
     v = layers.dense(src, params["wv"], ctx, "attn").reshape(b, src.shape[1], kvh, hd)
 
     if cfg.use_qk_norm and "q_norm_scale" in params:
@@ -329,7 +332,7 @@ def attention(
                 window=window, softcap=softcap, scale=scale,
             )
             out = constrain(out.reshape(b, s, h * hd).astype(x.dtype), DP, None, TP)
-            return layers.dense(out, params["wo"], ctx, "attn"), new_cache
+            return _out_proj(out, params, ctx), new_cache
         with jax.named_scope("cache_update"):
             if per_row:
                 starts = jnp.asarray(cache_pos, jnp.int32)
@@ -411,4 +414,9 @@ def attention(
             )
     out = out.reshape(b, s, h * hd).astype(x.dtype)
     out = constrain(out, DP, None, TP)
-    return layers.dense(out, params["wo"], ctx, "attn"), new_cache
+    return _out_proj(out, params, ctx), new_cache
+
+
+def _out_proj(out, params, ctx: Ctx):
+    return layers.scale(layers.dense(out, params["wo"], ctx, "attn"),
+                        ctx.cfg.attn_out_multiplier)

@@ -18,7 +18,8 @@ from repro.configs.base import ApproxConfig, ModelConfig
 from repro.distributed.sharding import DP, TP, constrain
 from repro.engine import dispatch as _engine, modes as _engine_modes
 
-__all__ = ["Ctx", "rms_norm", "rope", "mrope", "dense", "mlp", "init_dense", "init_mlp"]
+__all__ = ["Ctx", "rms_norm", "rope", "mrope", "dense", "mlp", "scale", "init_dense",
+           "init_mlp"]
 
 
 @dataclasses.dataclass
@@ -64,6 +65,15 @@ def init_mlp(key, cfg: ModelConfig, dtype) -> dict:
 
 
 # ------------------------------------------------------------------- layers
+def scale(x: jax.Array, m: float) -> jax.Array:
+    """``x * m`` rounded once to ``x``'s dtype (a muP multiplier), or ``x``
+    itself where ``m`` is 1: the check is in Python, so a model without
+    multipliers traces no multiply."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
@@ -144,6 +154,7 @@ def mlp(params: dict, x: jax.Array, ctx: Ctx) -> jax.Array:
     act = jax.nn.silu if ctx.cfg.ffn_activation == "silu" else (
         lambda v: jax.nn.gelu(v, approximate=True)
     )
-    h = act(dense(x, params["w1"], ctx, "mlp")) * dense(x, params["w3"], ctx, "mlp")
+    gate_m, down_m = ctx.cfg.mlp_multipliers
+    h = act(scale(dense(x, params["w1"], ctx, "mlp"), gate_m)) * dense(x, params["w3"], ctx, "mlp")
     h = constrain(h, DP, None, TP)
-    return dense(h, params["w2"], ctx, "mlp")
+    return scale(dense(h, params["w2"], ctx, "mlp"), down_m)

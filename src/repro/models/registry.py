@@ -78,6 +78,10 @@ class Model:
             return encdec.init_dec_caches(self.cfg, batch, max_seq, mem_len, dtype)
         return transformer.init_caches(self.cfg, batch, max_seq, dtype)
 
+    def state_bytes(self, caches) -> int:
+        """Bytes of recurrent state (conv + SSM / RG-LRU) in ``caches``."""
+        return 0 if self.cfg.is_encdec else transformer.state_bytes(caches)
+
     # ------------------------------------------------- enc-dec extras
     def encode(self, params, src_embeds, src_pos, ctx):
         assert self.cfg.is_encdec

@@ -7,6 +7,11 @@ stacked parameters (compile time and HLO size stay O(group), not
 O(depth)), with any remainder layers unrolled.  Remat (configurable
 policy) wraps the group body.
 
+An ``attn_ssd`` block (Falcon-H1) runs global attention and an SSD mixer
+side by side on one normed input and adds their outputs before the MLP;
+its cache is a :class:`HybridCache`, a KV cache and an SSD state side by
+side.
+
 Caches (KV / RG-LRU / SSD states) are pytrees stacked the same way and
 threaded through the scan as (xs -> ys).  A decode step (one token over
 caches) is the exception for KV caches: the scan reads the stacked pool
@@ -21,7 +26,7 @@ computed in vocab-chunked form, see train/steps.py).
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,12 +37,21 @@ from repro.models import attention, layers, moe, rglru, ssd
 from repro.models.layers import Ctx
 
 __all__ = [
+    "HybridCache",
     "init_params",
     "init_caches",
     "forward",
     "lm_head",
     "block_kinds",
+    "state_bytes",
 ]
+
+
+class HybridCache(NamedTuple):
+    """An ``attn_ssd`` layer's cache: its KV cache and its SSD state."""
+
+    kv: attention.KVCache
+    ssd: ssd.SSDCache
 
 
 def block_kinds(cfg: ModelConfig) -> list[str]:
@@ -60,6 +74,9 @@ def _init_block(key, cfg: ModelConfig, kind: str, dtype) -> dict:
         p["rglru"] = rglru.init_rglru(k1, cfg, dtype)
     elif kind == "ssd":
         p["ssd"] = ssd.init_ssd(k1, cfg, dtype)
+    elif kind == "attn_ssd":
+        p["attn"] = attention.init_attn(k1, cfg, dtype)
+        p["ssd"] = ssd.init_ssd(k3, cfg, dtype)
     else:
         raise ValueError(f"unknown block kind {kind!r}")
     if cfg.use_post_norm:
@@ -95,7 +112,20 @@ def _apply_block(
     elif kind == "rglru":
         out, new_cache = rglru.rglru_block(params["rglru"], h, ctx, cache=cache)
     elif kind == "ssd":
-        out, new_cache = ssd.ssd_block(params["ssd"], h, ctx, cache=cache)
+        out, new_cache = ssd.ssd_block(params["ssd"], h, ctx, cache=cache,
+                                       positions=positions)
+    elif kind == "attn_ssd":
+        with jax.named_scope("attn"):
+            a_out, kv = attention.attention(
+                params["attn"], h, positions, ctx,
+                cache=None if cache is None else cache.kv, cache_pos=cache_pos,
+            )
+        m_out, state = ssd.ssd_block(params["ssd"], h, ctx,
+                                     cache=None if cache is None else cache.ssd,
+                                     positions=positions)
+        with jax.named_scope("hybrid"):
+            out = m_out + a_out
+        new_cache = None if cache is None else HybridCache(kv, state)
     else:
         raise ValueError(kind)
     if cfg.use_post_norm:
@@ -122,6 +152,9 @@ def _apply_block(
 def _init_cache_for(cfg: ModelConfig, kind: str, batch: int, max_seq: int, dtype):
     if kind in ("attn_global", "attn_local"):
         return attention.init_kv_cache(cfg, batch, max_seq, dtype)
+    if kind == "attn_ssd":
+        return HybridCache(attention.init_kv_cache(cfg, batch, max_seq, dtype),
+                           ssd.init_ssd_cache(cfg, batch, dtype))
     if kind == "rglru":
         return rglru.init_rglru_cache(cfg, batch, dtype)
     if kind == "ssd":
@@ -132,10 +165,24 @@ def _init_cache_for(cfg: ModelConfig, kind: str, batch: int, max_seq: int, dtype
 def _commit(kind: str, cache: Any, out: Any, cache_pos, decode: bool):
     """A layer's cache after the forward.  In a decode step an attention
     layer hands back only the new token's k/v, written here; every other
-    layer and step hands back its whole new cache."""
+    layer and step hands back its whole new cache (a hybrid layer, its
+    whole new SSD state beside its new k/v)."""
+    if decode and kind == "attn_ssd":
+        return HybridCache(attention.write_kv(cache.kv, out.kv, cache_pos), out.ssd)
     if decode and kind in ("attn_global", "attn_local"):
         return attention.write_kv(cache, out, cache_pos)
     return out
+
+
+def state_bytes(caches) -> int:
+    """Bytes of recurrent state (conv and SSM / RG-LRU state, every layer,
+    every row) in a cache pytree, KV caches left out; 0 for attention-only
+    stacks."""
+    recurrent = (ssd.SSDCache, rglru.RGLRUCache)
+    nodes = jax.tree_util.tree_leaves(
+        caches, is_leaf=lambda c: isinstance(c, recurrent))
+    return sum(a.size * a.dtype.itemsize for c in nodes if isinstance(c, recurrent)
+               for a in c)
 
 
 # --------------------------------------------------------------------- init
@@ -227,6 +274,7 @@ def forward(
         x = embeds.astype(jnp.dtype(cfg.dtype))
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+    x = layers.scale(x, cfg.embed_multiplier)
     x = constrain(x, DP, TP if cfg.seq_shard_residuals else None, None)
 
     period = len(cfg.layer_pattern)
@@ -289,6 +337,7 @@ def lm_head(params: dict, hidden: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Full logits (B, S, V).  Use only for small S (decode / smoke tests)."""
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", hidden.astype(jnp.float32), w.astype(jnp.float32))
+    logits = layers.scale(logits, cfg.lm_head_multiplier)
     if cfg.final_logit_softcap:
         logits = jnp.tanh(logits / cfg.final_logit_softcap) * cfg.final_logit_softcap
     logits = constrain(logits, DP, None, TP)

@@ -36,10 +36,14 @@ SLO-adaptive accuracy-tier switching become first-class, measurable
 behaviors (docs/serving.md §Admission policies).
 
 Scope: decoder-only families.  Per-row position masking is exact for
-attention caches; recurrent-state families (RG-LRU / SSD) integrate left
-pads into their state, so admitting a padded prompt for them is rejected
-(serve those with buckets equal to the true prompt length).
-Encoder-decoder configs are rejected at construction.
+attention caches, and SSD layers (``ssd``, ``attn_ssd``) zero their pads
+out of the conv and SSM state, so both admit padded prompts; the row's
+conv and SSM state are seated with its KV by the same scatter.  RG-LRU
+layers still integrate left pads into their state, so admitting a padded
+prompt for them is rejected (serve those with buckets equal to the true
+prompt length).  A decode strategy that rolls back rejected tokens
+cannot undo a recurrent state update, and is refused for any layer with
+recurrent state.  Encoder-decoder configs are rejected at construction.
 """
 
 from __future__ import annotations
@@ -91,21 +95,25 @@ def __getattr__(name):
         )
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
-RECURRENT_KINDS = ("rglru", "ssd")  # layer kinds with pad-absorbing state
+# layer kinds whose state folds in every token it sees (no rollback)
+STATEFUL_KINDS = ("rglru", "ssd", "attn_ssd")
+# of those, the kinds whose prefill cannot mask left pads out of the state
+PAD_ABSORBING_KINDS = ("rglru",)
 
 
-def has_recurrent_state(cfg) -> bool:
-    return any(k in RECURRENT_KINDS for k in cfg.layer_pattern)
+def absorbs_pads(cfg) -> bool:
+    """Whether some layer of ``cfg`` would absorb left pads into its state."""
+    return any(k in PAD_ABSORBING_KINDS for k in cfg.layer_pattern)
 
 
 def supports_continuous(cfg) -> bool:
     """Whether the continuous scheduler fully supports ``cfg`` — including
     padded admission of mixed-length prompts.  One predicate shared by the
     scheduler's own checks and the CLI's auto-selection, so they cannot
-    drift: attention-only decoder stacks qualify; encoder-decoder configs
-    are rejected at construction and recurrent-state families reject
-    padded admission."""
-    return not cfg.is_encdec and not has_recurrent_state(cfg)
+    drift: attention and SSD decoder stacks qualify; encoder-decoder
+    configs are rejected at construction and RG-LRU stacks reject padded
+    admission."""
+    return not cfg.is_encdec and not absorbs_pads(cfg)
 
 
 def _apply_pool_quality(model, quality):
@@ -244,13 +252,20 @@ class ContinuousScheduler:
         if batch_size < 1 or prompt_len < 1 or max_new < 1:
             raise ValueError("batch_size, prompt_len and max_new must be >= 1")
         model, self.quality = _apply_pool_quality(model, quality)
-        # recurrent-state layers integrate left pads into their state
-        # (positions cannot mask them out), so padded admission would be
-        # silently wrong — enforced per request in _pad
-        self._recurrent = has_recurrent_state(model.cfg)
+        # RG-LRU layers integrate left pads into their state (positions
+        # cannot mask them out), so padded admission would be silently
+        # wrong — enforced per request in _pad
+        self._absorbs_pads = absorbs_pads(model.cfg)
         self.model, self.params = model, params
         self.batch_size, self.prompt_len, self.max_new = batch_size, prompt_len, max_new
         self.strategy = get_strategy(strategy)
+        stateful = sorted({k for k in model.cfg.layer_pattern if k in STATEFUL_KINDS})
+        if self.strategy.rolls_back and stateful:
+            raise ValueError(
+                f"decode strategy {self.strategy.name!r} rolls back rejected tokens, "
+                f"but {model.cfg.name} has layers with recurrent state ({stateful}) "
+                f"that a rollback cannot undo; serve it with the greedy strategy"
+            )
         # physical per-row cache: the logical window plus whatever spare
         # tail the strategy needs (speculative verify writes up to k past
         # the last committed slot before rollback)
@@ -316,11 +331,11 @@ class ContinuousScheduler:
             raise ValueError(
                 f"request {req.id}: budget {req.max_new} exceeds slot capacity {self.max_new}"
             )
-        if self._recurrent and ln < self.prompt_len:
+        if self._absorbs_pads and ln < self.prompt_len:
             raise ValueError(
                 f"request {req.id}: prompt length {ln} < bucket {self.prompt_len}, "
-                f"but {self.model.cfg.name} has recurrent-state layers that would "
-                f"integrate the left pads (positions cannot mask recurrent state); "
+                f"but {self.model.cfg.name} has recurrent-state (RG-LRU) layers that "
+                f"would integrate the left pads (positions cannot mask their state); "
                 f"use a bucket equal to the prompt length, or pad prompts upstream"
             )
         toks = np.zeros((self.prompt_len,), np.int32)
@@ -595,6 +610,7 @@ class ContinuousScheduler:
                             seat(i, req, int(tok0s[i]), t_b - t0, pool=True)
                 else:
                     caches = self.model.init_caches(B, self.capacity, self._cache_dtype)
+            state_bytes = self.model.state_bytes(caches)
             while True:
                 with span("tick"):
                     if open_loop:
@@ -764,6 +780,7 @@ class ContinuousScheduler:
             spec_accepted=spec_accepted,
             modeled_cost=modeled_cost,
             spans=spans.totals(),
+            state_bytes=state_bytes,
         )
         accounting = SlotAccounting(
             seated=seated_total,

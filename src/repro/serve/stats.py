@@ -212,6 +212,9 @@ class ServeStats:
     modeled_cost: float = 0.0  # sum of round costs in exact-step units
     # ---- host spans (continuous scheduler; empty for old readers)
     spans: dict = dataclasses.field(default_factory=dict)  # name -> SpanStat
+    # bytes of recurrent state (conv + SSM / RG-LRU, every layer, every
+    # slot) the pool holds; 0 for attention-only stacks
+    state_bytes: int = 0
 
     @property
     def host_s(self) -> Optional[float]:

@@ -219,6 +219,9 @@ class DecodeStrategy:
     """
 
     name = "greedy"
+    # whether a round may write tokens it later rejects: the pool must then
+    # hold no recurrent state, which a rollback cannot restore
+    rolls_back = False
 
     @property
     def extra_capacity(self) -> int:
@@ -292,6 +295,7 @@ class SelfSpeculative(DecodeStrategy):
     """
 
     name = "speculative"
+    rolls_back = True
 
     def __init__(self, k: int = 4, draft_tier: str = "draft",
                  verify_tier: Optional[str] = None):

@@ -85,6 +85,8 @@ def loss_fn(params, batch: dict, rng, model: Model) -> tuple[jax.Array, dict]:
     hidden, _, aux = model.forward(
         params, batch.get("tokens"), _positions(cfg, batch), ctx, **kwargs
     )
+    if cfg.lm_head_multiplier != 1.0:  # lm_head's (h W) m, as (h m) W
+        hidden = hidden.astype(jnp.float32) * cfg.lm_head_multiplier
     ce = chunked_cross_entropy(
         hidden, _head_matrix(params, cfg), batch["labels"], softcap=cfg.final_logit_softcap
     )

@@ -140,3 +140,59 @@ def test_decode_step_reads_pool_in_place(one_chip, cell):
     assert len(re.findall(r" while\(", hlo)) == 1, "a loop besides the layer scan"
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
+
+
+def test_falcon_h1_stage_fits_one_chip(one_chip):
+    """falcon-h1-34b-8l's exact-tier steps at its cell's pool (32 slots of
+    128 + 256), compiled for the chip: the decode step, a batch-1
+    admission and the pool prefill each fit one v5e's 16 GiB with their
+    arguments, outputs not aliased to them and temporaries; the decode step
+    writes its pool in place and copies no stacked KV pool."""
+    import re
+
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+    from repro.serve.scheduler import _apply_pool_quality, _scatter_row
+    from repro.serve.strategy import build_tier_engine
+
+    rows, bucket = 32, 128
+    model, tier = _apply_pool_quality(
+        build_model(get_config("falcon-h1-34b", num_layers=8)), "exact")
+    cfg = model.cfg
+    engine = build_tier_engine(model, POOL_SLOTS, name=tier, key=tier,
+                               scatter_row=_scatter_row)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = abstract(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    pool = abstract(jax.eval_shape(
+        lambda: model.init_caches(rows, POOL_SLOTS, jnp.dtype(cfg.dtype))))
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    # 32 x 8 x (32 heads x 128 x 256 f32 state + 3 x 5120 bf16 conv inputs)
+    assert model.state_bytes(pool) == 32 * 8 * (32 * 128 * 256 * 4 + 3 * 5120 * 2)
+    steps = {
+        "decode": engine.decode.lower(params, pool, ints(rows, 1), ints(rows), ints(rows)),
+        "admit": engine.admit_step.lower(params, pool, ints(1, bucket), ints(1, bucket),
+                                         ints()),
+        "pool_prefill": engine.prefill_pool.lower(params, ints(rows, bucket),
+                                                  ints(rows, bucket)),
+    }
+    hbm = 16 * 2 ** 30
+    compiled = {name: lowered.compile() for name, lowered in steps.items()}
+    for name, step in compiled.items():
+        mem = step.memory_analysis()
+        held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        # the weights are 12.23 GB; what is left must hold the pool and
+        # the step's temporaries with room for the runtime's own
+        assert held < 0.92 * hbm, f"{name}: {held / 1e9:.2f} GB"
+        if name != "pool_prefill":
+            assert mem.alias_size_in_bytes == pool_bytes, f"{name} does not write the pool in place"
+    hlo = compiled["decode"].as_text()
+    stacked = f"[{cfg.num_layers},{rows},{POOL_SLOTS},{cfg.num_kv_heads},{cfg.head_dim}]"
+    assert not re.findall(rf"= \w+{re.escape(stacked)}\S* copy\(", hlo), "the KV pool is copied"

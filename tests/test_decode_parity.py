@@ -11,10 +11,10 @@ from repro.models.registry import build_model
 from repro.train.steps import make_decode_step, make_prefill_step
 
 # covers: GQA global, local+softcap+postnorm, MQA+RG-LRU hybrid, SSD,
-# MoE, M-RoPE, enc-dec cross-attention
+# MoE, M-RoPE, enc-dec cross-attention, parallel attention+SSD (muP)
 ARCHS = [
     "qwen3-0.6b", "gemma2-9b", "recurrentgemma-2b", "mamba2-130m",
-    "granite-moe-1b-a400m", "qwen2-vl-7b", "seamless-m4t-large-v2",
+    "granite-moe-1b-a400m", "qwen2-vl-7b", "seamless-m4t-large-v2", "falcon-h1-34b",
 ]
 B, PROMPT, GEN = 2, 8, 6
 

@@ -164,10 +164,11 @@ def test_admission_rejects_oversized_requests(served):
 
 
 def test_recurrent_family_rejects_padded_admission():
-    """RG-LRU/SSD state integrates left pads (positions cannot mask it),
-    so padded admission must raise instead of silently decoding wrong —
-    full-length prompts still serve fine."""
-    cfg = get_config("mamba2-130m").reduced()
+    """RG-LRU state integrates left pads (positions cannot mask it), so
+    padded admission must raise instead of silently decoding wrong —
+    full-length prompts still serve fine.  (SSD layers mask their pads:
+    tests/test_falcon_h1.py.)"""
+    cfg = get_config("recurrentgemma-2b").reduced()
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
