@@ -166,7 +166,10 @@ def _ssd_step(xh, dt, a, b_in, c_in, state):
     k = h // g
     dtg = dt.reshape(bsz, g, k)
     da = jnp.exp(dtg * a.reshape(g, k))  # (B, G, K)
-    dbx = jnp.einsum("bgk,bgn,bgkp->bgkpn", dtg, b_in, xh.reshape(bsz, g, k, p))
+    # (dt x) B as broadcasts, not an einsum: XLA then fuses the update into
+    # the in-place write of the layer scan's carried state
+    dtx = dtg[..., None] * xh.reshape(bsz, g, k, p)
+    dbx = dtx[..., None] * b_in[:, :, None, None, :]
     state = da[..., None, None] * state.reshape(bsz, g, k, p, n) + dbx
     y = jnp.einsum("bgn,bgkpn->bgkp", c_in, state)
     return y.reshape(bsz, h, p), state.reshape(bsz, h, p, n)

@@ -14,10 +14,13 @@ side.
 
 Caches (KV / RG-LRU / SSD states) are pytrees stacked the same way and
 threaded through the scan as (xs -> ys).  A decode step (one token over
-caches) is the exception for KV caches: the scan reads the stacked pool
-as ``xs`` without rewriting it, emits each layer's new (B, 1, KV, hd) k/v
-as ``ys``, and one ``attention.write_kv`` after the scan writes all of
-them into the (donated) pool.
+caches) is the exception.  The scan reads the stacked KV pool as ``xs``
+without rewriting it, emits each layer's new (B, 1, KV, hd) k/v as
+``ys``, and one ``attention.write_kv`` after the scan writes all of them
+into the (donated) pool.  Recurrent state (conv and SSM / RG-LRU state),
+which a decode step reads and rewrites whole, rides in the scan's carry:
+each layer reads its slice at the scan index and writes its new state
+back in place, so the carried stack is the donated pool itself.
 
 The forward pass returns final *hidden states*; logits are produced by
 ``lm_head()`` (or, in training, never fully materialized — the loss is
@@ -166,7 +169,8 @@ def _commit(kind: str, cache: Any, out: Any, cache_pos, decode: bool):
     """A layer's cache after the forward.  In a decode step an attention
     layer hands back only the new token's k/v, written here; every other
     layer and step hands back its whole new cache (a hybrid layer, its
-    whole new SSD state beside its new k/v)."""
+    whole new SSD state beside its new k/v; in the layer scan, recurrent
+    state was already written into the carried pool)."""
     if decode and kind == "attn_ssd":
         return HybridCache(attention.write_kv(cache.kv, out.kv, cache_pos), out.ssd)
     if decode and kind in ("attn_global", "attn_local"):
@@ -174,15 +178,37 @@ def _commit(kind: str, cache: Any, out: Any, cache_pos, decode: bool):
     return out
 
 
+def _is_recurrent(cache) -> bool:
+    return isinstance(cache, (ssd.SSDCache, rglru.RGLRUCache))
+
+
+def _split_recurrent(caches) -> tuple[Any, Any]:
+    """(caches with each recurrent node set to None, the recurrent nodes
+    with every other leaf set to None): two pytrees that
+    ``_join_recurrent`` puts back together; (caches, None) where there is
+    no recurrent node."""
+    nodes = jax.tree_util.tree_leaves(caches, is_leaf=_is_recurrent)
+    if not any(_is_recurrent(c) for c in nodes):
+        return caches, None
+
+    def keep(want):
+        return lambda c: c if _is_recurrent(c) == want else None
+
+    rest = jax.tree_util.tree_map(keep(False), caches, is_leaf=_is_recurrent)
+    return rest, jax.tree_util.tree_map(keep(True), caches, is_leaf=_is_recurrent)
+
+
+def _join_recurrent(rest, recurrent):
+    return jax.tree_util.tree_map(lambda r, c: c if r is None else r, recurrent, rest,
+                                  is_leaf=lambda c: c is None or _is_recurrent(c))
+
+
 def state_bytes(caches) -> int:
     """Bytes of recurrent state (conv and SSM / RG-LRU state, every layer,
     every row) in a cache pytree, KV caches left out; 0 for attention-only
     stacks."""
-    recurrent = (ssd.SSDCache, rglru.RGLRUCache)
-    nodes = jax.tree_util.tree_leaves(
-        caches, is_leaf=lambda c: isinstance(c, recurrent))
-    return sum(a.size * a.dtype.itemsize for c in nodes if isinstance(c, recurrent)
-               for a in c)
+    nodes = jax.tree_util.tree_leaves(caches, is_leaf=_is_recurrent)
+    return sum(a.size * a.dtype.itemsize for c in nodes if _is_recurrent(c) for a in c)
 
 
 # --------------------------------------------------------------------- init
@@ -284,8 +310,13 @@ def forward(
 
     if repeats:
         def group_body(carry, xs):
-            x, aux = carry
-            gparams, gcache = xs
+            x, aux, state = carry
+            gparams, gcache, layer = xs
+            if state is not None:
+                # a decode step: this group's recurrent state, read from the carry
+                gcache = _join_recurrent(gcache, jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+                    state))
             out = {}
             for i in range(period):
                 kind = cfg.layer_pattern[i]
@@ -294,21 +325,31 @@ def forward(
                     gparams[f"sub{i}"], kind, x, positions, ctx, sub_cache, cache_pos
                 )
                 aux = aux + a
-            return (x, aux), (out if gcache is not None else None)
+            if state is not None:
+                # ...and written back into it in place
+                out, new_state = _split_recurrent(out)
+                state = jax.tree_util.tree_map(
+                    lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+                    state, new_state)
+            return (x, aux, state), (out if gcache is not None else None)
 
         body = _remat(group_body, cfg)
         scan_caches = caches.get("scan") if caches else None
         if scan_caches is None:
             # keep xs pytree structure static: pass params only
-            (x, aux), _ = jax.lax.scan(
-                lambda c, p: (body(c, (p, None))[0], None),
-                (x, jnp.float32(0.0)),
+            (x, aux, _), _ = jax.lax.scan(
+                lambda c, p: (body(c, (p, None, None))[0], None),
+                (x, jnp.float32(0.0), None),
                 params["scan"],
             )
         else:
-            (x, aux), outs = jax.lax.scan(
-                body, (x, jnp.float32(0.0)), (params["scan"], scan_caches)
+            read, state = _split_recurrent(scan_caches) if decode else (scan_caches, None)
+            (x, aux, state), outs = jax.lax.scan(
+                body, (x, jnp.float32(0.0), state),
+                (params["scan"], read, None if state is None else jnp.arange(repeats)),
             )
+            if state is not None:
+                outs = _join_recurrent(outs, state)
             new_caches["scan"] = {
                 f"sub{i}": _commit(kind, scan_caches[f"sub{i}"], outs[f"sub{i}"],
                                    cache_pos, decode)

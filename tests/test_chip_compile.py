@@ -20,6 +20,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.engine import config as engine_config
@@ -142,6 +143,35 @@ def test_decode_step_reads_pool_in_place(one_chip, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
 
 
+FALCON_ROWS = 32  # falcon-h1-34b-8l's cell: 32 slots of 128 + 256
+
+
+def _falcon_stage(sharding):
+    """falcon-h1-34b-8l's exact-tier model and engine, its abstract weights
+    and pool on ``sharding``, and a maker of abstract int32 operands."""
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+    from repro.serve.scheduler import _apply_pool_quality, _scatter_row
+    from repro.serve.strategy import build_tier_engine
+
+    model, tier = _apply_pool_quality(
+        build_model(get_config("falcon-h1-34b", num_layers=8)), "exact")
+    engine = build_tier_engine(model, POOL_SLOTS, name=tier, key=tier,
+                               scatter_row=_scatter_row)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    params = abstract(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    pool = abstract(jax.eval_shape(
+        lambda: model.init_caches(FALCON_ROWS, POOL_SLOTS, jnp.dtype(model.cfg.dtype))))
+    return model, engine, params, pool, ints
+
+
 def test_falcon_h1_stage_fits_one_chip(one_chip):
     """falcon-h1-34b-8l's exact-tier steps at its cell's pool (32 slots of
     128 + 256), compiled for the chip: the decode step, a batch-1
@@ -150,28 +180,9 @@ def test_falcon_h1_stage_fits_one_chip(one_chip):
     writes its pool in place and copies no stacked KV pool."""
     import re
 
-    from repro.configs.registry import get_config
-    from repro.models.registry import build_model
-    from repro.serve.scheduler import _apply_pool_quality, _scatter_row
-    from repro.serve.strategy import build_tier_engine
-
-    rows, bucket = 32, 128
-    model, tier = _apply_pool_quality(
-        build_model(get_config("falcon-h1-34b", num_layers=8)), "exact")
+    rows, bucket = FALCON_ROWS, 128
+    model, engine, params, pool, ints = _falcon_stage(one_chip)
     cfg = model.cfg
-    engine = build_tier_engine(model, POOL_SLOTS, name=tier, key=tier,
-                               scatter_row=_scatter_row)
-
-    def abstract(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    params = abstract(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    pool = abstract(jax.eval_shape(
-        lambda: model.init_caches(rows, POOL_SLOTS, jnp.dtype(cfg.dtype))))
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
     # 32 x 8 x (32 heads x 128 x 256 f32 state + 3 x 5120 bf16 conv inputs)
     assert model.state_bytes(pool) == 32 * 8 * (32 * 128 * 256 * 4 + 3 * 5120 * 2)
@@ -196,3 +207,58 @@ def test_falcon_h1_stage_fits_one_chip(one_chip):
     hlo = compiled["decode"].as_text()
     stacked = f"[{cfg.num_layers},{rows},{POOL_SLOTS},{cfg.num_kv_heads},{cfg.head_dim}]"
     assert not re.findall(rf"= \w+{re.escape(stacked)}\S* copy\(", hlo), "the KV pool is copied"
+
+
+def _hlo_computation(hlo: str, name: str) -> str:
+    """The text of the computation ``name`` in a compiled module's HLO."""
+    import re
+
+    return re.search(rf"^(?:ENTRY )?%{re.escape(name)} .*?^}}", hlo, re.M | re.S).group(0)
+
+
+def test_falcon_h1_decode_passes_state_once(one_chip):
+    """falcon-h1-34b-8l's exact decode step, compiled for the chip, carries
+    the stacked f32 SSM state through the layer scan in place: no copy of
+    the stack and no buffer allocated for a second one, no per-layer slice
+    materialised outside a fusion, one op in the loop body that writes a
+    layer's state, small temporaries, and the whole pool still aliased."""
+    import re
+
+    rows = FALCON_ROWS
+    model, engine, params, pool, ints = _falcon_stage(one_chip)
+    cfg = model.cfg
+    compiled = engine.decode.lower(params, pool, ints(rows, 1), ints(rows),
+                                   ints(rows)).compile()
+    hlo = compiled.as_text()
+    state = pool["scan"]["sub0"].ssd.state
+    layer = state.shape[1:]
+    assert state.shape == (cfg.num_layers, rows, cfg.ssm_heads, cfg.ssm_head_dim,
+                           cfg.ssm_state) and state.dtype == jnp.float32
+    stacked = "f32[" + ",".join(map(str, state.shape)) + "]"
+    assert not re.findall(rf"= {re.escape(stacked)}\S* copy\(", hlo), "the state stack is copied"
+    assert not re.findall(rf"= {re.escape(stacked)}\S* custom-call\(.*AllocateBuffer", hlo), \
+        "a second state stack is allocated"
+
+    entry = re.search(r"^ENTRY %(\S+) ", hlo, re.M).group(1)
+    body = re.search(r" while\(.*?body=%([\w.\-]+)", hlo).group(1)
+    per_layer = "f32[1," + ",".join(map(str, layer)) + "]"
+    held = []
+    for name in (entry, body):
+        text = _hlo_computation(hlo, name)
+        sliced = re.findall(rf"= {re.escape(per_layer)}\S* (?!bitcast\()[\w-]+\(", text)
+        assert not sliced, f"a layer's state is materialised in {name}: {sliced}"
+        if name == body:
+            # each op's result type (a tuple for a multi-output fusion), op name
+            for result, op in re.findall(r"^\s+(?:ROOT )?%\S+ = (.*?) ([\w-]+)\((?:%|\))",
+                                         text, re.M):
+                sizes = [np.prod([int(d) for d in dims.split(",")])
+                         for dims in re.findall(r"f32\[([\d,]+)\]", result)]
+                if (op not in ("get-tuple-element", "bitcast", "parameter", "tuple")
+                        and max(sizes, default=0) >= np.prod(layer)):
+                    held.append(f"{op} -> {result}")
+    assert len(held) <= 1, f"ops in the layer loop holding a layer's state: {held}"
+
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.6e9, f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries"
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pool))
+    assert mem.alias_size_in_bytes == pool_bytes, "the decode step does not write the pool in place"
